@@ -210,6 +210,9 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_count=2")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     out_lines = []
     t0 = time.time()
 

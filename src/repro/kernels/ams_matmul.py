@@ -27,16 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import PackLayout
-
-# renamed TPUCompilerParams -> CompilerParams in newer jax; same signature
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-if _CompilerParams is None:  # pragma: no cover - future jax renames
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; this jax version is unsupported by the AMS "
-        "Pallas kernel")
-
+from repro.kernels.tuning import vmem_usage
 
 # --------------------------------------------------------------------------
 # In-kernel bit restoration (shared by both containers AND by the paged
@@ -142,7 +133,8 @@ def ams_matmul_padded(
     """Core pallas_call on pre-padded operands.
 
     x: [B, K] (B % bb == 0, K % bk == 0), hi/lsb padded to matching rows,
-    scale: [1, N] (N % bn == 0).
+    scale: [1, N] (N % bn == 0). The compiler's VMEM limit is the tile's
+    `tuning.vmem_usage` estimate, so the estimate is held true on chip.
     """
     nb, nn, nk = B // bb, N // bn, K // bk
     pw = lay.per_word
@@ -154,8 +146,9 @@ def ams_matmul_padded(
     out_spec = pl.BlockSpec((bb, bn), lambda b, n, k: (b, n))
     grid = (nb, nn, nk)
     scratch = [pltpu.VMEM((bb, bn), jnp.float32)]
-    params = _CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_usage(lay, bb, bk, bn),
     )
 
     if lay.container == "fp533":

@@ -35,13 +35,14 @@ Two lowering tiers share the math:
     fused kernel does not support.
   * `fused_paged_attention` / `fused_contiguous_attention` — the Pallas
     template (`impl="pallas"`/`"pallas_interpret"`), one grid
-    (B, kv_heads, kv_blocks) with the KV dimension innermost
-    ("arbitrary") and (m, l, acc) in VMEM scratch across it.
+    (B, kv_blocks) with the KV dimension innermost ("arbitrary"); each
+    cell holds every local kv head of its block and keeps one (m, l, acc)
+    per head in VMEM scratch across the KV dimension.
 
 `attend_contiguous` is the dispatch the models cores call: it routes to
-the fused template when the impl asks for it AND the case is fusable
-(group-major layout, no mesh collectives, no ring/sliding window), and
-otherwise falls back to the bit-identical XLA path. Contiguous block
+the fused template when the impl asks for it, and raises when the case is
+not fusable (mesh collectives, ring/sliding window, a head map that is not
+group-major) rather than run the XLA path under a Pallas name. Contiguous block
 sizes come from `kernels.tuning.plan_attention_tiles` — a persistent
 per-(shape, family, scheme) autotune cache with a deterministic
 VMEM-budgeted default; set ``REPRO_ATTN_MEASURE=1`` to pick the block by
@@ -62,8 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import get_scheme
 from repro.core.kv_quant import codes_from_planes, packed_head_dim
-# _CompilerParams: the CompilerParams/TPUCompilerParams rename shim
-from repro.kernels.ams_matmul import _CompilerParams, decode_codes_to_f32
+from repro.kernels.ams_matmul import decode_codes_to_f32
 from repro.kernels.tuning import plan_attention_tiles
 
 NEG_BIG = -2e30   # additive mask; exp(NEG_BIG - NEG_CLAMP) == 0 exactly
@@ -241,20 +241,25 @@ def row_lengths(len_ref, b, c: int, g: int):
     """Per-ROW valid-key counts [c*g, 1] for a chunked query block: the
     flattened lengths ride scalar prefetch as [B*c]; row r of the (c, g)-
     folded query block belongs to query r // g. c and g are static, so the
-    gather is c scalar SMEM reads."""
-    lv = jnp.stack([len_ref[b * c + j] for j in range(c)])      # [c]
-    return jnp.repeat(lv, g, total_repeat_length=c * g)[:, None]
+    gather is c scalar SMEM reads, each selected into its g rows (a vector
+    compare per query: Mosaic lowers no gather, scatter or 0-sized slice)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (c * g, 1), 0)
+    out = jnp.zeros((c * g, 1), jnp.int32)
+    for j in range(c):
+        out = jnp.where(row // g == j, len_ref[b * c + j], out)
+    return out
 
 
 def online_softmax_step(qf, k_blk, v_blk, length, i, nb, o_ref,
                         acc_ref, m_ref, l_ref, *, pv_dtype=jnp.float32):
-    """One KV block of flash-decode accumulation — THE loop body every
-    fused layout shares. qf [rows, hd] f32 (pre-scaled; rows = chunk*group
-    for ragged blocks), k_blk [block, hd] / v_blk [block, hd_v] f32,
-    ``length`` a scalar or per-row [rows, 1] valid-key count. ``pv_dtype``
-    mirrors flash_decode's ``p.astype(v.dtype)`` before the PV product
-    (bf16 caches cast, AMS lattice values stay f32) so the oracle and the
-    kernel round alike."""
+    """One KV block of flash-decode accumulation for ONE kv head — THE loop
+    body every fused layout shares. qf [rows, hd] f32 (pre-scaled; rows =
+    chunk*group for ragged blocks), k_blk [block, hd] / v_blk [block, hd_v]
+    f32, ``length`` a scalar or per-row [rows, 1] valid-key count; the refs
+    are that head's views of the output block and the scratch.
+    ``pv_dtype`` mirrors flash_decode's ``p.astype(v.dtype)`` before the PV
+    product (bf16 caches cast, AMS lattice values stay f32) so the oracle
+    and the kernel round alike."""
     block = k_blk.shape[0]
 
     @pl.when(i == 0)
@@ -289,19 +294,24 @@ def online_softmax_step(qf, k_blk, v_blk, length, i, nb, o_ref,
 
 
 # --- K/V load hooks -------------------------------------------------------
-def _load_pair(kv_refs):
+# Every KV block holds ALL local kv heads: a [1, block, kv, last] window of
+# a [.., block-axis, kv, last] operand, so the block's last two dims equal
+# the array's (the only legal TPU block for a head axis that sits
+# second-to-last with fewer than 8 heads). A hook reads head ``h`` with a
+# static second-minor index.
+def _load_pair(kv_refs, h: int):
     """Separate K and V tensors (bf16/f32 pages or contiguous rows)."""
     k_ref, v_ref = kv_refs
-    return (k_ref[0, :, 0, :].astype(jnp.float32),
-            v_ref[0, :, 0, :].astype(jnp.float32))
+    return (k_ref[0, :, h, :].astype(jnp.float32),
+            v_ref[0, :, h, :].astype(jnp.float32))
 
 
 def _make_load_stream(hd_v: int):
     """One compressed stream; values are its first hd_v columns (absorbed
     MLA) — V costs zero extra HBM reads."""
-    def load(kv_refs):
+    def load(kv_refs, h: int):
         (k_ref,) = kv_refs
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, :, h, :].astype(jnp.float32)
         return k, k[:, :hd_v]
     return load
 
@@ -309,24 +319,23 @@ def _make_load_stream(hd_v: int):
 def _make_load_ams(fmt, k_share: int, hd: int, hd_v: Optional[int]):
     """AMS packed planes; with ``hd_v`` set, a single quantized stream whose
     values are the first hd_v restored columns."""
-    def load(kv_refs):
+    def restore(hi, lsb, sc, h):
+        return restore_page(hi[0, :, h, :], lsb[0, :, h, :], sc[0, :, h, :],
+                            fmt, k_share, hd)
+
+    def load(kv_refs, h: int):
         if hd_v is None:
             khi, klsb, ksc, vhi, vlsb, vsc = kv_refs
-            k = restore_page(khi[0, :, 0, :], klsb[0, :, 0, :],
-                             ksc[0, :, 0, :], fmt, k_share, hd)
-            v = restore_page(vhi[0, :, 0, :], vlsb[0, :, 0, :],
-                             vsc[0, :, 0, :], fmt, k_share, hd)
-            return k, v
-        khi, klsb, ksc = kv_refs
-        k = restore_page(khi[0, :, 0, :], klsb[0, :, 0, :],
-                         ksc[0, :, 0, :], fmt, k_share, hd)
+            return restore(khi, klsb, ksc, h), restore(vhi, vlsb, vsc, h)
+        k = restore(*kv_refs, h)
         return k, k[:, :hd_v]
     return load
 
 
-def _make_body(*, load_kv, nb: int, chunk: int, g: int, pv_dtype,
+def _make_body(*, load_kv, nb: int, chunk: int, g: int, kv_n: int, pv_dtype,
                num_scalars: int):
-    """Assemble one kernel body from a load hook. Ref order is fixed by the
+    """Assemble one kernel body from a load hook: one grid cell walks one
+    KV block for every local kv head in turn. Ref order is fixed by the
     grid spec: [scalar prefetch...(lengths last), q, *kv operands, out,
     acc, m, l]."""
     def body(*refs):
@@ -334,12 +343,13 @@ def _make_body(*, load_kv, nb: int, chunk: int, g: int, pv_dtype,
         q_ref = refs[num_scalars]
         kv_refs = refs[num_scalars + 1:-4]
         o_ref, acc_ref, m_ref, l_ref = refs[-4:]
-        b, i = pl.program_id(0), pl.program_id(2)
-        qf = q_ref[0, 0].astype(jnp.float32)
-        k_blk, v_blk = load_kv(kv_refs)
-        online_softmax_step(qf, k_blk, v_blk,
-                            row_lengths(len_ref, b, chunk, g), i, nb,
-                            o_ref, acc_ref, m_ref, l_ref, pv_dtype=pv_dtype)
+        b, i = pl.program_id(0), pl.program_id(1)
+        lengths = row_lengths(len_ref, b, chunk, g)
+        for h in range(kv_n):
+            k_blk, v_blk = load_kv(kv_refs, h)
+            online_softmax_step(q_ref[0, h].astype(jnp.float32), k_blk, v_blk,
+                                lengths, i, nb, o_ref.at[0, h], acc_ref.at[h],
+                                m_ref.at[h], l_ref.at[h], pv_dtype=pv_dtype)
     return body
 
 
@@ -376,19 +386,19 @@ def _unfold_o(o, dims, hd_v: int, chunked: bool, dtype):
 
 
 def _launch(body, grid, num_scalars, in_specs, out_spec, scalar_args,
-            operands, *, rows, hd_v, interpret):
-    scratch = [pltpu.VMEM((rows, hd_v), jnp.float32),   # acc
-               pltpu.VMEM((rows, 128), jnp.float32),    # m (col 0 live)
-               pltpu.VMEM((rows, 128), jnp.float32)]    # l (col 0 live)
-    B, kv_n, _ = grid
+            operands, *, kv_n, rows, hd_v, interpret):
+    scratch = [pltpu.VMEM((kv_n, rows, hd_v), jnp.float32),   # acc
+               pltpu.VMEM((kv_n, rows, 128), jnp.float32),    # m (col 0 live)
+               pltpu.VMEM((kv_n, rows, 128), jnp.float32)]    # l (col 0 live)
+    B, _ = grid
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalars, grid=grid,
         in_specs=in_specs, out_specs=out_spec, scratch_shapes=scratch)
     return pl.pallas_call(
         body, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kv_n, rows, hd_v), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*scalar_args, *operands)
 
@@ -412,8 +422,9 @@ def fused_paged_attention(
     """Paged flash-decode through the template. Requires the group-major
     GQA head layout (the only layout the model zoo emits — see
     `kv_index_map`); returns q's shape in q.dtype. One grid step attends
-    one (slot, kv-head, page) cell; the block table and the flattened
-    per-query lengths ride the same scalar-prefetch stream, so each page's
+    one (slot, page) cell for every local kv head; the block table and the
+    flattened per-query lengths ride the same scalar-prefetch stream, so
+    each page's
     BlockSpec index_map dereferences ``block_table[b, i]`` BEFORE the body
     runs and the pipeline DMAs exactly the pages the slot owns."""
     kv_n = jax.tree.leaves(pool["k"])[0].shape[2]
@@ -425,22 +436,20 @@ def fused_paged_attention(
     bt_flat = block_table.reshape(-1).astype(jnp.int32)
 
     # index maps: scalar-prefetch refs arrive after the grid indices
-    q_spec = pl.BlockSpec((1, 1, rows, hd), lambda b, h, i, bt, ln: (b, h, 0, 0))
-    out_spec = pl.BlockSpec((1, 1, rows, hd_v),
-                            lambda b, h, i, bt, ln: (b, h, 0, 0))
+    q_spec = pl.BlockSpec((1, kv_n, rows, hd), lambda b, i, bt, ln: (b, 0, 0, 0))
+    out_spec = pl.BlockSpec((1, kv_n, rows, hd_v),
+                            lambda b, i, bt, ln: (b, 0, 0, 0))
 
-    def page_spec(block_tail):
-        return pl.BlockSpec(
-            (1, page) + block_tail,
-            lambda b, h, i, bt, ln: (bt[b * nb + i], 0, h) + (0,) * (len(block_tail) - 1))
+    def page_spec(last):
+        return pl.BlockSpec((1, page, kv_n, last),
+                            lambda b, i, bt, ln: (bt[b * nb + i], 0, 0, 0))
 
     if kv_scheme is not None:
         scheme = get_scheme(kv_scheme)
         hd_p = packed_head_dim(hd, scheme)
         gw = pool["k"]["lsb"].shape[-1]
         load = _make_load_ams(scheme.base, scheme.k, hd, value_slice)
-        plane_specs = [page_spec((1, hd_p // 2)), page_spec((1, gw)),
-                       page_spec((1, 1))]
+        plane_specs = [page_spec(hd_p // 2), page_spec(gw), page_spec(1)]
         operands = [qf, pool["k"]["hi"], pool["k"]["lsb"], pool["k"]["scale"]]
         in_specs = [q_spec] + plane_specs
         if value_slice is None:
@@ -450,18 +459,18 @@ def fused_paged_attention(
     else:
         if value_slice is None:
             load = _load_pair
-            in_specs = [q_spec, page_spec((1, hd)), page_spec((1, hd))]
+            in_specs = [q_spec, page_spec(hd), page_spec(hd)]
             operands = [qf, pool["k"], pool["v"]]
         else:
             load = _make_load_stream(value_slice)
-            in_specs = [q_spec, page_spec((1, hd))]
+            in_specs = [q_spec, page_spec(hd)]
             operands = [qf, pool["k"]]
         pv_dtype = jax.tree.leaves(pool["k"])[0].dtype
 
-    body = _make_body(load_kv=load, nb=nb, chunk=c, g=g, pv_dtype=pv_dtype,
-                      num_scalars=2)
-    o = _launch(body, (B, kv_n, nb), 2, in_specs, out_spec,
-                (bt_flat, lens), operands, rows=rows, hd_v=hd_v,
+    body = _make_body(load_kv=load, nb=nb, chunk=c, g=g, kv_n=kv_n,
+                      pv_dtype=pv_dtype, num_scalars=2)
+    o = _launch(body, (B, nb), 2, in_specs, out_spec,
+                (bt_flat, lens), operands, kv_n=kv_n, rows=rows, hd_v=hd_v,
                 interpret=interpret)
     return _unfold_o(o, dims, hd_v, chunked, q.dtype)
 
@@ -481,7 +490,7 @@ def fused_contiguous_attention(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Contiguous-cache flash-decode through the same template: grid
-    (B, kv_heads, S_loc/block_kv), cache rows DMA'd block-by-block, lengths
+    (B, S_loc/block_kv), cache rows DMA'd block-by-block, lengths
     on scalar prefetch. ``block_kv`` comes from the per-(shape, family)
     autotune cache (`kernels.tuning.plan_attention_tiles`) unless
     overridden; candidates are divisors of S_loc so no block ever reads
@@ -504,17 +513,18 @@ def fused_contiguous_attention(
 
     def run(bk: int):
         nb = S_loc // bk
-        q_spec = pl.BlockSpec((1, 1, rows, hd), lambda b, h, i, ln: (b, h, 0, 0))
-        out_spec = pl.BlockSpec((1, 1, rows, hd_v),
-                                lambda b, h, i, ln: (b, h, 0, 0))
-        kv_spec = pl.BlockSpec((1, bk, 1, hd), lambda b, h, i, ln: (b, i, h, 0))
+        q_spec = pl.BlockSpec((1, kv_n, rows, hd), lambda b, i, ln: (b, 0, 0, 0))
+        out_spec = pl.BlockSpec((1, kv_n, rows, hd_v),
+                                lambda b, i, ln: (b, 0, 0, 0))
+        kv_spec = pl.BlockSpec((1, bk, kv_n, hd), lambda b, i, ln: (b, i, 0, 0))
         in_specs = [q_spec] + [kv_spec] * n_kv
         operands = ([qf, k_cache, v_cache] if value_slice is None
                     else [qf, k_cache])
-        body = _make_body(load_kv=load, nb=nb, chunk=c, g=g,
+        body = _make_body(load_kv=load, nb=nb, chunk=c, g=g, kv_n=kv_n,
                           pv_dtype=pv_dtype, num_scalars=1)
-        o = _launch(body, (B, kv_n, nb), 1, in_specs, out_spec, (lens,),
-                    operands, rows=rows, hd_v=hd_v, interpret=interpret)
+        o = _launch(body, (B, nb), 1, in_specs, out_spec, (lens,),
+                    operands, kv_n=kv_n, rows=rows, hd_v=hd_v,
+                    interpret=interpret)
         return o
 
     if block_kv is None:
@@ -564,26 +574,29 @@ def attend_contiguous(
     ``impl="ref"`` (the serving default) IS `flash_decode` /
     `flash_decode_chunk` — bit-identical to the pre-template cores.
     ``impl="pallas"``/``"pallas_interpret"`` lowers through the fused
-    template when the case is fusable; sequence-sharded cores
-    (``axis_name``), ring / sliding-window caches and non-group-major head
-    maps silently keep the XLA path (the collectives and ring index math
-    live only there)."""
-    fused = impl in ("pallas", "pallas_interpret")
-    if fused:
+    template; a case the template cannot take (sequence-sharded cores
+    with ``axis_name``, ring / sliding-window caches, non-group-major head
+    maps — the collectives and ring index math live only in the XLA path)
+    raises instead of silently running XLA, as `cache.paged_attend`
+    does."""
+    if impl in ("pallas", "pallas_interpret"):
         H, kv_n = q.shape[-2], k_cache.shape[2]
         grouped = (H % kv_n == 0) and np.array_equal(
             np.asarray(kv_map), np.arange(H) // (H // kv_n))
         if axis_name is not None or window or ring or not grouped:
-            fused = False
-    if not fused:
-        if q.ndim == 3:
-            return flash_decode(q, k_cache, v_cache, lengths, kv_map=kv_map,
-                                axis_name=axis_name, window=window, ring=ring,
-                                scale=scale)
-        return flash_decode_chunk(q, k_cache, v_cache, lengths, kv_map=kv_map,
-                                  axis_name=axis_name, scale=scale)
-    return fused_contiguous_attention(
-        q, k_cache, lengths,
-        v_cache=None if value_slice is not None else v_cache,
-        value_slice=value_slice, scale=scale,
-        interpret=(impl == "pallas_interpret"))
+            raise NotImplementedError(
+                f"impl={impl!r} fused attention needs a group-major head map "
+                f"and an unsharded, non-windowed cache (axis_name="
+                f"{axis_name!r}, window={window}, ring={ring}, "
+                f"group-major={grouped}); use impl='ref'")
+        return fused_contiguous_attention(
+            q, k_cache, lengths,
+            v_cache=None if value_slice is not None else v_cache,
+            value_slice=value_slice, scale=scale,
+            interpret=(impl == "pallas_interpret"))
+    if q.ndim == 3:
+        return flash_decode(q, k_cache, v_cache, lengths, kv_map=kv_map,
+                            axis_name=axis_name, window=window, ring=ring,
+                            scale=scale)
+    return flash_decode_chunk(q, k_cache, v_cache, lengths, kv_map=kv_map,
+                              axis_name=axis_name, scale=scale)

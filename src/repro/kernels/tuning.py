@@ -43,19 +43,31 @@ class TilePlan:
     pipeline_buffers: int = 2  # double buffering
 
 
+# Unpack + restore working set of one hi-plane tile, per K row of the tile:
+# ``row + col * bn`` bytes, keyed by codes per int32 word. It covers the
+# double-buffered packed-plane DMAs, the per-segment shifts, the
+# position-order stack/reshape relayout and the f32 restore — everything
+# the kernel body holds that scales with bk. Fitted as an upper bound
+# (3-4 % above) to the scoped VMEM the v5e compiler reports for
+# ams_matmul at bb=8 over bk x bn in {one or two K blocks} x {128, 256,
+# 512}, for every scheme in core.formats.SCHEMES. The kernel compiles
+# with its VMEM limit set to `vmem_usage`, so an estimate that falls
+# short fails the TPU compile tests instead of passing unnoticed.
+_RESTORE_BYTES_PER_K_ROW = {4: (1000, 20.0), 5: (13300, 4.8),
+                            6: (7200, 4.0), 8: (8300, 5.3)}
+
+
 def vmem_usage(lay: PackLayout, bb: int, bk: int, bn: int,
                buffers: int = 2) -> int:
     """Bytes of VMEM a (bb, bk, bn) tile claims in ams_matmul."""
-    k = lay.scheme.k
-    hi = 4 * (bk // lay.per_word) * bn
-    lsb = 4 * (bk // (32 * k)) * bn if (lay.container == "planes" and k > 1) else 0
+    row, col = _RESTORE_BYTES_PER_K_ROW[lay.per_word]
     x = 4 * bb * bk
     scale = 4 * bn
-    streams = buffers * (hi + lsb + x + scale)        # double-buffered DMAs
-    decoded = 4 * bk * bn                              # f32 restore tile
+    streams = buffers * (x + scale)                    # double-buffered DMAs
+    restore = int(bk * (row + col * bn))               # planes + restore
     acc = 4 * bb * bn                                  # f32 accumulator
     out = 4 * bb * bn
-    return streams + decoded + acc + out
+    return streams + restore + acc + out
 
 
 def plan_tiles(lay: PackLayout, B: int, K: int, N: int,
@@ -96,11 +108,12 @@ class AttnTilePlan:
 
 def attn_vmem_usage(rows: int, block_kv: int, hd: int,
                     hd_v: Optional[int] = None, scheme: Optional[str] = None,
-                    buffers: int = 2) -> int:
+                    buffers: int = 2, heads: int = 1) -> int:
     """Bytes of VMEM one (rows, block_kv) attention cell claims: the
     double-buffered K/V streams (packed planes for an AMS scheme, else f32
     upper bound), the in-VREG restore tiles, q, the f32 accumulator and the
-    (rows, 128) m/l scratch columns."""
+    (rows, 128) m/l scratch columns — for each of the ``heads`` kv heads a
+    cell holds."""
     hd_v = hd if hd_v is None else hd_v
     if scheme is not None:
         fmt = get_scheme(scheme)
@@ -116,7 +129,7 @@ def attn_vmem_usage(rows: int, block_kv: int, hd: int,
     acc = 4 * rows * hd_v
     ml = 2 * 4 * rows * 128
     out = 4 * rows * hd_v
-    return streams + decoded + q + acc + ml + out
+    return heads * (streams + decoded + q + acc + ml + out)
 
 
 def attn_plan_key(*, kind: str, family: str, scheme: Optional[str],
@@ -219,13 +232,14 @@ def plan_attention_tiles(*, kind: str, family: str, scheme: Optional[str],
     hit = cache.get(key)
     if hit is not None and (measure is None or hit.source == "measured"):
         return hit
+    heads = max(kv_heads, 1)
     if kind == "paged":
-        plan = AttnTilePlan(page, rows,
-                            attn_vmem_usage(rows, page, hd, hd_v, scheme))
+        plan = AttnTilePlan(page, rows, attn_vmem_usage(
+            rows, page, hd, hd_v, scheme, heads=heads))
         cache.put(key, plan)
         return plan
     cands = [AttnTilePlan(bk, rows, attn_vmem_usage(rows, bk, hd, hd_v,
-                                                    scheme))
+                                                    scheme, heads=heads))
              for bk in _divisors_desc(s_max)]
     fitting = [p for p in cands if p.vmem_bytes <= budget]
     if not fitting:

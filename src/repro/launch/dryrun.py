@@ -24,7 +24,7 @@ import traceback  # noqa: E402
 import jax  # noqa: E402
 
 from repro.analysis.hlo import collective_bytes, hlo_op_histogram  # noqa: E402
-from repro.launch.mesh import make_production_mesh, use_mesh  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.specs import all_cells, make_run_config  # noqa: E402
 from repro.launch.steps import build_step  # noqa: E402
 
@@ -35,7 +35,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, out_dir: str,
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
     rcfg = make_run_config(arch, shape, **(rc_overrides or {}))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, arg_shapes, _shardings = build_step(mesh, rcfg.model, rcfg)
         lowered = jitted.lower(*arg_shapes.values())
         t_lower = time.time() - t0

@@ -144,7 +144,7 @@ from repro.configs import get_config
 from repro.configs.base import RunConfig
 from repro.core.policy import QuantPolicy
 from repro.launch.config import EngineConfig
-from repro.launch.mesh import make_driver_mesh, use_mesh
+from repro.launch.mesh import make_driver_mesh
 from repro.launch.sampling import (
     GREEDY,
     SamplingParams,
@@ -163,8 +163,14 @@ from repro.launch.scheduler import (
     SpilledState,
 )
 from repro.launch.steps import build_engine_step, engine_step_signature
-from repro.models import init_params, make_cache, model_dims, reset_cache_slot
-from repro.models.common import quantize_params
+from repro.models import (
+    cast_float_weights,
+    init_serving_params,
+    make_cache,
+    model_dims,
+    quantize_params,
+    reset_cache_slot,
+)
 from repro.obs import MetricsRegistry, ObsConfig, TraceRecorder, build_cost_model
 from repro.obs.metrics import COUNT_BUCKETS, NULL_REGISTRY, TIME_BUCKETS
 
@@ -329,31 +335,8 @@ class ServeEngine:
             else make_driver_mesh(ec.mesh_kind)
         seed, drafter, verbose = ec.seed, ec.drafter, ec.verbose
 
-        with use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             tp = self.mesh.shape["model"]
-            if params is None:
-                params = init_params(jax.random.PRNGKey(seed), cfg, tp=tp)
-            params = jax.tree.map(
-                lambda x: x.astype(jnp.bfloat16) if x.ndim >= 2 else x, params)
-            if quant is not None:
-                t0 = time.time()
-                params = quantize_params(params, quant)
-                if verbose:
-                    print(f"[ptq] quantized to {ec.scheme} ({ec.strategy}) "
-                          f"in {time.time()-t0:.1f}s", flush=True)
-            self.params = params
-            # the CacheConfig threads through for contiguous caches too:
-            # its impl field routes the decode cores through the fused
-            # attention template (kernels.attention_template)
-            self.cache = make_cache(cfg, slots, capacity, tp=tp,
-                                    dtype=jnp.bfloat16, cache_cfg=ccfg)
-            # per-device KV residency: a head-sharded paged pool (kv heads
-            # divide the model axis — the same rule steps.py/pool_shardings
-            # apply) keeps 1/tp of the pool on each device, so all
-            # kv-bytes-per-token accounting below is PER DEVICE
-            _dims = model_dims(cfg, tp)
-            self._kv_shards = (tp if (ccfg.paged and tp > 1
-                                      and _dims.kv % tp == 0) else 1)
             # arg shapes are kept for obs.cost.hlo_step_cost: lowering the
             # jitted step at its serving shapes yields the compiled
             # program's achieved per-tick HBM/FLOP cost
@@ -361,6 +344,36 @@ class ServeEngine:
                 self.mesh, cfg, self.rcfg, cache_cfg=ccfg,
                 chunk=self.step_chunk, sampling=True,
                 speculate_k=self.speculate_k)
+            t0 = time.time()
+            if params is None:
+                # one layer group at a time: the float tree never exists
+                params = init_serving_params(jax.random.PRNGKey(seed), cfg,
+                                             tp=tp, quant=quant)
+            else:
+                # float weights to bf16 and (re)quantize; packed linears
+                # pass through untouched
+                params = cast_float_weights(params)
+                if quant is not None:
+                    params = quantize_params(params, quant)
+            if verbose and quant is not None:
+                print(f"[ptq] quantized to {ec.scheme} ({ec.strategy}) "
+                      f"in {time.time()-t0:.1f}s", flush=True)
+            # the jitted step's in_shardings expect exactly this placement
+            self.params = params = jax.device_put(params, _shardings["params"])
+            # the CacheConfig threads through for contiguous caches too:
+            # its impl field routes the decode cores through the fused
+            # attention template (kernels.attention_template)
+            self.cache = jax.jit(
+                lambda: make_cache(cfg, slots, capacity, tp=tp,
+                                   dtype=jnp.bfloat16, cache_cfg=ccfg),
+                out_shardings=_shardings["cache"])()
+            # per-device KV residency: a head-sharded paged pool (kv heads
+            # divide the model axis — the same rule steps.py/pool_shardings
+            # apply) keeps 1/tp of the pool on each device, so all
+            # kv-bytes-per-token accounting below is PER DEVICE
+            _dims = model_dims(cfg, tp)
+            self._kv_shards = (tp if (ccfg.paged and tp > 1
+                                      and _dims.kv % tp == 0) else 1)
             # host->device spill restores happen OUTSIDE the jitted step;
             # on a tp>1 mesh the restored cache must be re-placed to the
             # step's expected sharding before the next dispatch
@@ -840,7 +853,7 @@ class ServeEngine:
         C = self.step_chunk              # token-buffer width fed to the step
         PC = self.chunk                  # prefill growth cap per slot
         tracing = self.trace.enabled
-        with use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             # 1) admit queued requests into free slots (see _admit)
             if tracing:
                 self.trace.begin(0, "tick", args={"tick": self.tick})
@@ -989,7 +1002,7 @@ class ServeEngine:
         t0, tracing, fed = p.t0, p.tracing, p.fed
         nvalid, ndraft, outs = p.nvalid, p.ndraft, p.outs
         paged = self.cache_cfg.paged
-        with use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             if tracing:
                 # time the device work to completion — dispatch is
                 # serialized under tracing, so trace runs are for
@@ -1188,14 +1201,12 @@ class ServeEngine:
 
     def _profile_tick_start(self) -> None:
         """Start the optional jax.profiler capture on the first served
-        tick (`ObsConfig.jax_profile_ticks`); disabled on any failure."""
+        tick (`ObsConfig.jax_profile_ticks`). Profiling was asked for, so
+        a failure to start raises."""
         if self._prof_ticks_left <= 0 or self._prof_active:
             return
-        try:
-            jax.profiler.start_trace(self.obs.jax_profile_dir)
-            self._prof_active = True
-        except Exception:              # platform without profiler support
-            self._prof_ticks_left = 0
+        jax.profiler.start_trace(self.obs.jax_profile_dir)
+        self._prof_active = True
 
     def _profile_tick_end(self) -> None:
         if not self._prof_active:

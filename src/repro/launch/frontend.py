@@ -291,6 +291,7 @@ def main(argv=None) -> None:
     import argparse
 
     from repro.cache import CacheConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.config import EngineConfig
 
     ap = argparse.ArgumentParser(
@@ -310,6 +311,7 @@ def main(argv=None) -> None:
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8000)
     a = ap.parse_args(argv)
+    enable_compile_cache()
     cache = (None if a.cache == "contiguous" else
              CacheConfig(kind=a.cache, page_size=a.page_size,
                          host_spill_pages=a.host_spill_pages))

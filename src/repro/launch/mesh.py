@@ -2,63 +2,18 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run sets XLA_FLAGS before any jax import; tests see 1 device).
-
-Also carries the small compat layer for older jax releases (0.4.x): no
-``jax.sharding.AxisType`` and no ``jax.set_mesh`` — ``make_mesh``/``use_mesh``
-below pick the right spelling so serving code runs on both.
+Every mesh uses Auto axis types; activate one with ``jax.set_mesh`` and
+enter manual regions with ``jax.shard_map``.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-try:  # jax >= 0.5: explicit/auto axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mk_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Context manager activating `mesh` as the ambient mesh.
-
-    ``jax.set_mesh`` on current jax; the Mesh object's own context manager
-    on older releases (sufficient for the Auto-axis style used here).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext(mesh)
-
-
-def compat_shard_map(f, mesh, axis_names, in_specs, out_specs):
-    """`jax.shard_map` across the supported jax range.
-
-    Current jax spells it ``jax.shard_map(..., axis_names=...,
-    check_vma=...)``; 0.4.x has only ``jax.experimental.shard_map`` with
-    ``check_rep``, where the region is manual over EVERY mesh axis (its
-    partial-manual ``auto=`` mode lowers to a PartitionId op XLA's CPU
-    SPMD partitioner rejects). Axes absent from the specs are then
-    manually replicated — same math for every region in this tree (none
-    runs collectives over its auto axes), at worst extra replication on
-    0.4.x."""
-    axis_names = set(axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, axis_names=axis_names,
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -16,6 +16,8 @@ carries the production mesh shardings.
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b --reduced \
         --scheme fp5.33-e2m3 --tokens 32
 
+``--no-reduced`` serves the published widths (a chip's worth of memory).
+
 For true streaming-arrival serving, construct ``ServeEngine`` directly (see
 examples/serve_continuous.py and benchmarks/bench_serving.py).
 """
@@ -27,6 +29,7 @@ import argparse
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.config import EngineConfig
 from repro.launch.engine import ServeEngine
 
@@ -82,10 +85,13 @@ def generate(arch: str, *, reduced=True, scheme="fp5.33-e2m3",
     return toks, stats
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced() toy widths (default); --no-reduced "
+                         "serves the published widths")
     ap.add_argument("--scheme", default="fp5.33-e2m3")
     ap.add_argument("--strategy", default="set_lsb")
     ap.add_argument("--impl", default="ref")
@@ -98,7 +104,12 @@ def main():
     ap.add_argument("--top-k", type=int, default=0)
     ap.add_argument("--top-p", type=float, default=1.0)
     ap.add_argument("--sample-seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
     sampling = None
     if args.temperature > 0:
         from repro.launch.sampling import SamplingParams

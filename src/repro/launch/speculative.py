@@ -167,7 +167,9 @@ class SelfDrafter(Drafter):
                                        policy=policy, ctx=None, remat=False)
             return jnp.argmax(logits[0], axis=-1).astype(jnp.int32)  # [S]
 
-        self._fwd = jax.jit(fwd)
+        # rounds like the engine step it drafts for (see build_engine_step)
+        self._fwd = jax.jit(
+            fwd, compiler_options={"xla_allow_excess_precision": False})
 
     def propose(self, history: np.ndarray, k: int) -> np.ndarray:
         h = np.asarray(history, np.int32)

@@ -26,7 +26,7 @@ from repro.models import (
     make_cache,
     model_dims,
 )
-from repro.models.common import quantize_params
+from repro.models.common import cast_float_weights, quantize_params
 from repro.optim import (
     AdamWConfig,
     apply_updates,
@@ -35,7 +35,7 @@ from repro.optim import (
     warmup_cosine,
 )
 from . import sharding as SH
-from .mesh import compat_shard_map, dp_axes, tp_axis
+from .mesh import dp_axes, tp_axis
 
 
 # ---------------------------------------------------------------------------
@@ -134,10 +134,10 @@ def build_train_step(mesh, cfg: ModelConfig, rcfg: RunConfig):
         g_specs = jax.tree.map(lambda _: P(), p_bf16)
         data_spec = P(None, "pod", None)
         pre_spec = P(None, "pod", None, None)
-        f = compat_shard_map(
-            inner, mesh, {"pod"},
+        f = jax.shard_map(
+            inner, mesh=mesh, axis_names={"pod"},
             in_specs=(p_specs, data_spec, data_spec, pre_spec),
-            out_specs=(g_specs, P()))
+            out_specs=(g_specs, P()), check_vma=False)
         return f(p_bf16, tok_m, tgt_m, pre_m)
 
     def step_fn(params, opt_state, tokens, targets, prefix, step):
@@ -201,9 +201,7 @@ def build_train_step(mesh, cfg: ModelConfig, rcfg: RunConfig):
 def quantized_param_shapes(cfg: ModelConfig, rcfg: RunConfig, tp: int):
     """Abstract shapes of the serving params (quantized per policy)."""
     def build(k):
-        p = init_params(k, cfg, tp=tp)
-        p = jax.tree.map(lambda x: x.astype(jnp.bfloat16)
-                         if x.ndim >= 2 else x, p)
+        p = cast_float_weights(init_params(k, cfg, tp=tp, dtype=jnp.bfloat16))
         if rcfg.quantized:
             p = quantize_params(p, rcfg.quant)
         return p
@@ -484,9 +482,14 @@ def build_engine_step(mesh, cfg: ModelConfig, rcfg: RunConfig,
         out_shardings = (tok_shard, tok_shard, c_shard)
     else:
         out_shardings = (tok_shard, c_shard)
+    # no excess precision: every bf16 value is rounded where the program
+    # says so. A tensor-parallel step rounds its all-gathered activations
+    # to bf16 regardless, so only this keeps its token streams
+    # bit-identical to the single-device step's
     jitted = jax.jit(engine_fn, in_shardings=in_shardings,
                      out_shardings=out_shardings,
-                     donate_argnums=(1 + arg_names.index("cache"),))
+                     donate_argnums=(1 + arg_names.index("cache"),),
+                     compiler_options={"xla_allow_excess_precision": False})
     # arg_shapes preserves the jitted signature's POSITIONAL order — the
     # dry-run lowers via `jitted.lower(*arg_shapes.values())`
     tok_shape = (B, chunk) if chunked else (B,)

@@ -29,7 +29,7 @@ from repro.launch.fault_tolerance import (
     StragglerMonitor,
     heartbeat_file,
 )
-from repro.launch.mesh import make_production_mesh, make_driver_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh, make_driver_mesh
 from repro.launch.steps import build_train_step
 from repro.models import init_params
 from repro.optim import init_state
@@ -69,7 +69,7 @@ def main(argv=None):
                      grad_compression=args.grad_compression)
     mesh = make_mesh(args.mesh)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, shapes, shards = build_train_step(mesh, cfg, rcfg)
         params = init_params(jax.random.PRNGKey(0), cfg,
                              tp=mesh.shape["model"])

@@ -265,8 +265,8 @@ def gqa_decode_core(q, k_new, v_new, cache_k, cache_v, pos, *,
                     impl="ref"):
     """Insert + attend. q: [B, H, hd]; k/v_new: [B, 1, kv, hd];
     caches [B, S_loc, kv, hd]. Runs inside shard_map when the cache is
-    sequence-sharded over `axis_name` (where ``impl`` always resolves to
-    the collective-carrying ref path)."""
+    sequence-sharded over `axis_name` (only ``impl="ref"`` carries the
+    collectives; a fused impl raises there)."""
     cache_k = cache_insert(cache_k, k_new, pos, axis_name, window if ring else 0)
     cache_v = cache_insert(cache_v, v_new, pos, axis_name, window if ring else 0)
     o = attend_contiguous(q, cache_k, cache_v, pos + 1, kv_map=kv_map,

@@ -119,6 +119,23 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
 
 
 # ----------------------------------------------------------- quantize tree
+def cast_float_weights(params, dtype=jnp.bfloat16, min_ndim: int = 2):
+    """Cast high-precision weights to the serving dtype: floating leaves of
+    rank >= ``min_ndim``. Packed AMS linears ({'hi', 'lsb', 'scale'}) are
+    returned untouched — their int planes and f32 channel scales are the
+    quantized representation itself, so a packed tree passes through."""
+    def visit(node):
+        if isinstance(node, dict):
+            if "hi" in node:
+                return node
+            return {k: visit(v) for k, v in node.items()}
+        if jnp.issubdtype(node.dtype, jnp.floating) and node.ndim >= min_ndim:
+            return node.astype(dtype)
+        return node
+
+    return visit(params)
+
+
 def quantize_params(params, policy: QuantPolicy, strategy: Optional[str] = None):
     """Offline PTQ pass: replace eligible {'w': [.., K, N]} linears by packed
     planes. Handles stacked leading dims (scan layers, MoE experts) via vmap.

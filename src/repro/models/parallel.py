@@ -33,12 +33,11 @@ class ParallelCtx:
         return self.dp_axes if self.dp_axes else None
 
     def shard_map(self, f, in_specs, out_specs):
-        """Manual collectives over the tp axis only; other axes stay auto
-        (`launch.mesh.compat_shard_map` picks the jax spelling)."""
+        """Manual collectives over the tp axis only; other axes stay auto."""
         assert self.mesh is not None and self.tp_axis is not None
-        from repro.launch.mesh import compat_shard_map
-        return compat_shard_map(f, self.mesh, {self.tp_axis},
-                                in_specs=in_specs, out_specs=out_specs)
+        return jax.shard_map(f, mesh=self.mesh, axis_names={self.tp_axis},
+                             in_specs=in_specs, out_specs=out_specs,
+                             check_vma=False)
 
 
 NO_CTX = ParallelCtx()

@@ -21,7 +21,8 @@ from . import attention as A
 from . import ffn as F
 from . import moe as M
 from . import ssm as S
-from .common import Dims, apply_linear, make_linear, make_norm, model_dims, rms_norm
+from .common import (Dims, apply_linear, cast_float_weights, make_linear,
+                     make_norm, model_dims, quantize_params, rms_norm)
 from .parallel import ParallelCtx
 
 
@@ -71,9 +72,11 @@ def init_block(key, cfg, dims: Dims, kind: str, dtype=jnp.float32):
     raise ValueError(f"unknown block kind {kind!r}")
 
 
-def init_params(key, cfg, tp: int = 1, dtype=jnp.float32):
+def init_params(key, cfg, tp: int = 1, dtype=jnp.float32, *,
+                map_layers=None):
     """Full parameter pytree. Pattern repeats stacked [G, ...] under 'layers';
-    remainder blocks unrolled under 'tail'."""
+    remainder blocks unrolled under 'tail'. ``map_layers(init_group, keys)``
+    builds the stacked groups (default: ``vmap`` over the group keys)."""
     dims = model_dims(cfg, tp)
     pat = layer_pattern(cfg)
     L, Pn = cfg.num_layers, len(pat)
@@ -85,10 +88,11 @@ def init_params(key, cfg, tp: int = 1, dtype=jnp.float32):
         return {f"sub{i}": init_block(kk[i], cfg, dims, pat[i], dtype)
                 for i in range(Pn)}
 
+    map_layers = map_layers or (lambda f, keys: jax.vmap(f)(keys))
     params: Dict[str, Any] = {
         "embed": {"w": (jax.random.normal(k_emb, (dims.V, cfg.d_model),
                                           jnp.float32) * 0.02).astype(dtype)},
-        "layers": jax.vmap(init_group)(jax.random.split(k_layers, G)),
+        "layers": map_layers(init_group, jax.random.split(k_layers, G)),
         "final_norm": make_norm(cfg.d_model, dtype),
         "lm_head": make_linear(k_head, cfg.d_model, dims.V, dtype=dtype),
     }
@@ -97,6 +101,49 @@ def init_params(key, cfg, tp: int = 1, dtype=jnp.float32):
         params["tail"] = {f"sub{i}": init_block(kk[i], cfg, dims, pat[i], dtype)
                           for i in range(R)}
     return params
+
+
+def init_serving_params(key, cfg, tp: int = 1, quant=None):
+    """The serving engine's weights: `init_params` drawn straight into
+    bf16 and, given a `QuantPolicy`, AMS-quantized by `quantize_params`.
+    The values are bit-identical to
+    ``quantize_params(cast_float_weights(init_params(key, cfg, tp)), quant)``
+    (each weight is drawn in f32 and rounded once either way); only the
+    rank-1 leaves outside the layer stack (norm gains, zero biases) are
+    bf16 here where that path keeps f32, with the same values.
+
+    The layer groups are generated, cast and quantized ONE AT A TIME and
+    written into preallocated stacked leaves, so the peak is the result
+    plus one group's temporaries rather than the whole float tree:
+    qwen2-7b's bf16 tree (15.2 GB) never exists, only its 6.5 GB packed
+    form. The groups run op by op, as the whole-tree path does: under
+    ``jit`` XLA folds the init scale into the sampler's own arithmetic,
+    turns the channel-scale division by a constant into a multiply and
+    fuses the quantizer's error sums, which moves low bits and flips
+    shared-LSB choices."""
+    def serve(tree, min_ndim):
+        # leaves a block creates in f32 on purpose are cast as the stacked
+        # whole-tree path casts them (a group leaf of rank r stacks to r+1)
+        tree = cast_float_weights(tree, min_ndim=min_ndim)
+        return quantize_params(tree, quant) if quant is not None else tree
+
+    put = jax.jit(lambda stacked, g, grp: jax.tree.map(
+        lambda a, b: a.at[g].set(b), stacked, grp), donate_argnums=(0,))
+
+    def map_layers(init_group, keys):
+        stacked = None
+        for g in range(keys.shape[0]):
+            grp = serve(init_group(keys[g]), 1)
+            if stacked is None:
+                stacked = jax.tree.map(
+                    lambda a: jnp.zeros((keys.shape[0],) + a.shape, a.dtype),
+                    grp)
+            stacked = put(stacked, g, grp)
+        return stacked
+
+    params = init_params(key, cfg, tp, jnp.bfloat16, map_layers=map_layers)
+    rest = serve({k: v for k, v in params.items() if k != "layers"}, 2)
+    return {**rest, "layers": params["layers"]}
 
 
 # ---------------------------------------------------------------------------

@@ -37,16 +37,9 @@ def compressed_psum(grads, axes: Sequence[str]):
     """
     axes = tuple(axes)
 
-    def world():
-        n = 1
-        for a in axes:
-            # jax.lax.axis_size is missing on 0.4.x; psum(1, axis) is the
-            # portable spelling (constant-folded under manual axes)
-            n *= (jax.lax.axis_size(a) if hasattr(jax.lax, "axis_size")
-                  else jax.lax.psum(1, a))
-        return n
-
-    w = world()
+    w = 1
+    for a in axes:
+        w *= jax.lax.axis_size(a)
 
     def one(g):
         gf = g.astype(jnp.float32)
@@ -68,8 +61,8 @@ def compressed_psum(grads, axes: Sequence[str]):
 
 def compressed_allreduce(grads, mesh, dp_axes: Sequence[str]):
     """Standalone wrapper: all-reduce replicated-view grads over dp_axes."""
-    from repro.launch.mesh import compat_shard_map
     specs = jax.tree.map(lambda _: P(), grads)
-    f = compat_shard_map(lambda g: compressed_psum(g, dp_axes), mesh,
-                         set(dp_axes), in_specs=(specs,), out_specs=specs)
+    f = jax.shard_map(lambda g: compressed_psum(g, dp_axes), mesh=mesh,
+                      axis_names=set(dp_axes), in_specs=(specs,),
+                      out_specs=specs, check_vma=False)
     return f(grads)

@@ -6,7 +6,8 @@ is pinned to the same oracle family —
   * ``impl="ref"`` IS `flash_decode`/`flash_decode_chunk` (bit-identical:
     `attend_contiguous` must return the very same arrays the pre-template
     cores computed), and unfusable cases (mesh collectives, ring/sliding
-    window, non-group-major head maps) silently keep that path;
+    window, non-group-major head maps) raise under a fused impl instead of
+    silently running that path;
   * the fused Pallas lowering (interpret mode here) agrees with the XLA
     oracle to f32-reduction tolerance across the full
     {gqa, mla} x {contiguous, paged_bf16, paged_ams} x chunk {1, 4} grid,
@@ -81,17 +82,23 @@ def test_ref_impl_is_flash_decode_bitwise():
 
 
 def test_unfusable_cases_fall_back_to_ref_bitwise():
-    """window/ring and non-group-major head maps must keep the XLA path
-    even when the fused impl is requested — same bits, no lowering error."""
+    """window/ring, sequence-sharded and non-group-major cases have no fused
+    lowering: a fused impl raises for them (no silent downgrade to XLA),
+    and impl="ref" still serves them bit-identically to flash_decode."""
     q, k, v, lengths, kvm, _ = _dense_case("gqa", 1)
-    got = attend_contiguous(q, k, v, lengths, kv_map=kvm,
-                            impl="pallas_interpret", window=4)
+    scrambled = np.array([1, 0, 1, 0], np.int32)      # not group-major
+    for impl in ("pallas", "pallas_interpret"):
+        for kw in (dict(kv_map=kvm, window=4), dict(kv_map=kvm, ring=True),
+                   dict(kv_map=kvm, axis_name="model"),
+                   dict(kv_map=scrambled)):
+            with pytest.raises(NotImplementedError, match="impl='ref'"):
+                attend_contiguous(q, k, v, lengths, impl=impl, **kw)
+    got = attend_contiguous(q, k, v, lengths, kv_map=kvm, impl="ref",
+                            window=4)
     np.testing.assert_array_equal(
         np.asarray(got),
         np.asarray(flash_decode(q, k, v, lengths, kv_map=kvm, window=4)))
-    scrambled = np.array([1, 0, 1, 0], np.int32)      # not group-major
-    got = attend_contiguous(q, k, v, lengths, kv_map=scrambled,
-                            impl="pallas_interpret")
+    got = attend_contiguous(q, k, v, lengths, kv_map=scrambled, impl="ref")
     np.testing.assert_array_equal(
         np.asarray(got),
         np.asarray(flash_decode(q, k, v, lengths, kv_map=scrambled)))

@@ -34,7 +34,7 @@ def run_py(body: str, devices: int = 8, timeout: int = 420) -> str:
 def test_moe_paths_agree():
     run_py("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.models.parallel import ParallelCtx
     from repro.models import moe as M
     from repro.configs import get_config
@@ -48,7 +48,7 @@ def test_moe_paths_agree():
     ctx = ParallelCtx(mesh=mesh, dp_axes=('data',), tp_axis='model')
     p = M.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 64))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_dense, aux_d = M.moe_dense(p, x, cfg)
         y_tp, aux_t = jax.jit(lambda p, x: M.moe_tp(p, x, cfg, ctx))(p, x)
         y_ep, aux_e = jax.jit(lambda p, x: M.moe_ep(p, x, cfg, ctx))(p, x)
@@ -66,7 +66,7 @@ def test_flash_decode_seq_sharded():
     import functools
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.models import attention as A
 
     mesh = make_test_mesh((2, 4), ('data', 'model'))
@@ -83,15 +83,15 @@ def test_flash_decode_seq_sharded():
     core = functools.partial(A.gqa_decode_core, kv_map=kvm)
     o_ref, ck_ref, cv_ref = core(q, kn, vn, ck, cv, pos)
 
-    from repro.launch.mesh import compat_shard_map
-    sharded = compat_shard_map(
-        functools.partial(core, axis_name='model'), mesh, {'model'},
+    sharded = jax.shard_map(
+        functools.partial(core, axis_name='model'), mesh=mesh,
+        axis_names={'model'}, check_vma=False,
         in_specs=(P(None, None, None), P(None, None, None, None),
                   P(None, None, None, None), P(None, 'model', None, None),
                   P(None, 'model', None, None), P()),
         out_specs=(P(None, None, None), P(None, 'model', None, None),
                    P(None, 'model', None, None)))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         o_s, ck_s, cv_s = jax.jit(sharded)(q, kn, vn, ck, cv, pos)
     np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_ref),
                                rtol=2e-5, atol=2e-5)
@@ -105,7 +105,7 @@ def test_ring_cache_decode_sharded():
     import functools
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.models import attention as A
 
     mesh = make_test_mesh((2, 4), ('data', 'model'))
@@ -120,13 +120,12 @@ def test_ring_cache_decode_sharded():
     kvm = A.kv_index_map(H, H, KV)
     core = functools.partial(A.gqa_decode_core, kv_map=kvm, window=W, ring=True)
     o_ref, *_ = core(q, kn, vn, ck, cv, pos)
-    from repro.launch.mesh import compat_shard_map
-    sharded = compat_shard_map(functools.partial(core, axis_name='model'),
-        mesh, {'model'},
+    sharded = jax.shard_map(functools.partial(core, axis_name='model'),
+        mesh=mesh, axis_names={'model'}, check_vma=False,
         in_specs=(P(None,None,None), P(None,None,None,None), P(None,None,None,None),
                   P(None,'model',None,None), P(None,'model',None,None), P()),
         out_specs=(P(None,None,None), P(None,'model',None,None), P(None,'model',None,None)))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         o_s, *_ = jax.jit(sharded)(q, kn, vn, ck, cv, pos)
     np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_ref), rtol=2e-5, atol=2e-5)
     print('ring cache sharded == ref')
@@ -136,14 +135,14 @@ def test_ring_cache_decode_sharded():
 def test_int8_compressed_allreduce():
     run_py("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.optim import compressed_allreduce
 
     mesh = make_test_mesh((8,), ('pod',))
     g = {'w': jnp.asarray(np.random.default_rng(0).standard_normal(1024),
                           jnp.float32),
          'tiny': jnp.ones((3,), jnp.float32)}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda g: compressed_allreduce(g, mesh, ('pod',)))(g)
     # psum over replicated = x * 8
     expect = g['w'] * 8
@@ -159,7 +158,7 @@ def test_int8_compressed_allreduce():
 def test_sharded_train_matches_single_device():
     run_py("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.launch.steps import build_train_step
     from repro.configs import get_config
     from repro.configs.base import RunConfig
@@ -173,7 +172,7 @@ def test_sharded_train_matches_single_device():
         mesh = make_test_mesh(shape, ('data', 'model'))
         rcfg = RunConfig(model=cfg, seq_len=32, global_batch=4, mode='train',
                          learning_rate=1e-3, warmup_steps=2)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             f, shapes, shards = build_train_step(mesh, cfg, rcfg)
             params = init_params(jax.random.PRNGKey(0), cfg,
                                  tp=mesh.shape['model'])

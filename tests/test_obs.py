@@ -20,6 +20,7 @@ The load-bearing contracts:
 
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -457,11 +458,21 @@ class TestObsConfig:
         c = ObsConfig(enabled=False, trace=True, cost=True)
         assert not c.trace_on and not c.cost_on   # master switch wins
 
-    def test_jax_profiler_capture_is_best_effort(self, tmp_path):
-        """jax_profile_ticks=N wraps the first N device steps; a profiler
-        that cannot start must disable itself, never crash serving."""
+    def test_jax_profiler_capture_is_best_effort(self, tmp_path, monkeypatch):
+        """jax_profile_ticks=N wraps the first N device steps and writes a
+        trace; profiling was asked for, so a profiler that cannot start
+        raises instead of serving on untraced."""
         eng = make_engine(obs=ObsConfig(jax_profile_ticks=1,
                                         jax_profile_dir=str(tmp_path)))
         reqs, _ = drive(eng, schedule()[:1])
         assert reqs[0].done
-        assert eng._prof_ticks_left == 0 or not eng._prof_active
+        assert eng._prof_ticks_left == 0 and not eng._prof_active
+        assert list(tmp_path.rglob("*.xplane.pb"))
+
+        def refuse(_):
+            raise RuntimeError("no profiler here")
+        monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+        eng = make_engine(obs=ObsConfig(jax_profile_ticks=1,
+                                        jax_profile_dir=str(tmp_path)))
+        with pytest.raises(RuntimeError, match="no profiler"):
+            drive(eng, schedule()[:1])

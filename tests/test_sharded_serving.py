@@ -12,8 +12,8 @@ stream drift is a real numerics change, not a shape artifact.
 
 Everything multi-device runs in a `run_py` subprocess (fresh python with
 XLA_FLAGS=--xla_force_host_platform_device_count set before jax init —
-same machinery as tests/test_distributed.py), so these tests exercise
-`mesh.compat_shard_map` on whatever jax the environment resolves.
+same machinery as tests/test_distributed.py), so these tests exercise the
+step's `jax.shard_map` regions on a real multi-device mesh.
 
 Also pinned here:
   * the host side is device-count-agnostic: page ids (block-table rows),
