@@ -87,9 +87,12 @@ Architecture (Orca-style iteration-level scheduling):
   * OBSERVABILITY is first-class (`repro.obs`, ``obs=ObsConfig(...)``):
     the engine owns a metrics registry every subsystem (scheduler,
     allocator, drafter) emits into, and ``stats()`` is computed from it
-    (bit-identical to the historical hand counters); ``ObsConfig(trace=
-    True)`` records per-request lifecycle + per-tick device-step spans as
-    a Perfetto-loadable Chrome trace, and the roofline cost model
+    (bit-identical to the historical hand counters); every tick is split
+    into phases stamped on the JAX profiler's clock (`obs.trace.TickPhases`:
+    a bounded record, a counter per phase, a profiler annotation per
+    phase); ``ObsConfig(trace=True)`` also keeps per-request lifecycle
+    spans and the tick phases as a Perfetto-loadable Chrome trace, and the
+    roofline cost model
     (`obs.cost`) attributes analytic floor HBM bytes/FLOPs to every tick
     and request. ``ObsConfig(enabled=False)`` swaps in no-op instruments —
     telemetry cannot perturb the measured system. See
@@ -171,8 +174,20 @@ from repro.models import (
     quantize_params,
     reset_cache_slot,
 )
-from repro.obs import MetricsRegistry, ObsConfig, TraceRecorder, build_cost_model
-from repro.obs.metrics import COUNT_BUCKETS, NULL_REGISTRY, TIME_BUCKETS
+from repro.obs import (
+    MetricsRegistry,
+    ObsConfig,
+    TickPhases,
+    TraceRecorder,
+    build_cost_model,
+    now_ns,
+)
+from repro.obs.metrics import (
+    COUNT_BUCKETS,
+    LATENCY_BUCKETS,
+    NULL_REGISTRY,
+    TIME_BUCKETS,
+)
 
 
 class RequestHandle:
@@ -194,6 +209,11 @@ class RequestHandle:
     def request(self) -> Request:
         """The underlying scheduler record (escape hatch; internals)."""
         return self._req
+
+    @property
+    def engine(self) -> "ServeEngine":
+        """The engine serving this request (read-only)."""
+        return self._eng
 
     @property
     def status(self) -> str:
@@ -319,6 +339,8 @@ class ServeEngine:
                         else NULL_REGISTRY)
         self.trace = TraceRecorder(enabled=self.obs.trace_on)
         self.trace.thread(0, "engine")
+        self.phases = TickPhases(enabled=self.obs.enabled,
+                                 metrics=self.metrics)
         quant = None
         if scheme != "fp16":
             quant = QuantPolicy(scheme=scheme, strategy=ec.strategy,
@@ -503,6 +525,14 @@ class ServeEngine:
         self._m_lat = m.histogram("serve_request_latency_ticks",
                                   "submit -> finish, engine ticks",
                                   buckets=COUNT_BUCKETS)
+        self._m_ttft_s = m.histogram(
+            "serve_request_ttft_seconds",
+            "submit -> first token on the host, wall seconds",
+            buckets=LATENCY_BUCKETS, keep_raw=False)
+        self._m_wait_s = m.histogram(
+            "serve_queue_wait_seconds",
+            "submit -> first admission, wall seconds",
+            buckets=LATENCY_BUCKETS, keep_raw=False)
         self._m_glen = m.histogram("serve_request_gen_tokens",
                                    "tokens generated per finished request",
                                    buckets=COUNT_BUCKETS)
@@ -532,12 +562,6 @@ class ServeEngine:
             self._m_kv_ach = m.counter(
                 "serve_kv_achieved_bytes_total",
                 "KV bytes the cache implementation touches")
-
-        # jax.profiler capture of the first obs.jax_profile_ticks served
-        # ticks (XLA-level trace; ObsConfig.jax_profile_dir)
-        self._prof_ticks_left = (self.obs.jax_profile_ticks
-                                 if self.obs.enabled else 0)
-        self._prof_active = False
 
     # ------------------------------------------------------------- frontend
     def submit(self, prompt, max_tokens: Optional[int] = None,
@@ -584,7 +608,7 @@ class ServeEngine:
             req = Request(rid=rid, prompt=prompt, max_tokens=max_tokens,
                           prefix_embeds=prefix_embeds, sampling=sp,
                           key_data=request_key(sp.seed, rid),
-                          priority=priority)
+                          priority=priority, submit_ns=now_ns())
             ccfg = self.cache_cfg
             if ccfg.paged and ccfg.prefix_cache and prefix_embeds is None:
                 # chain hash per FULL prompt page — the prefix-cache
@@ -728,6 +752,7 @@ class ServeEngine:
                 self.cache = self._reset(self.cache, slot)
             if not resumed:
                 self._m_prompt.inc(req.n_prefix + req.prompt_len)
+                req.admit_ns = now_ns()
             if self.trace.enabled:
                 self.trace.end(req.rid + 1,
                                "preempted" if resumed else "queued",
@@ -849,26 +874,22 @@ class ServeEngine:
         if self._pending is not None:
             raise RuntimeError("step already in flight (step_end not called)")
         t0 = time.perf_counter()
+        ph = self.phases
+        ph.begin_tick()                  # engine.admit
         paged = self.cache_cfg.paged
         C = self.step_chunk              # token-buffer width fed to the step
         PC = self.chunk                  # prefill growth cap per slot
         tracing = self.trace.enabled
         with jax.set_mesh(self.mesh):
             # 1) admit queued requests into free slots (see _admit)
-            if tracing:
-                self.trace.begin(0, "tick", args={"tick": self.tick})
-                self.trace.begin(0, "admit")
             self._admit()
-            if tracing:
-                self.trace.end(0, "admit")
 
             if self.active_count == 0:
                 # idle ticks still advance the engine clock — open-loop
                 # drivers gate future arrivals on eng.tick
+                self._close_tick({"idle": True})
                 self.tick += 1
                 self._m_idle.inc()
-                if tracing:
-                    self.trace.end(0, "tick", args={"idle": True})
                 with self._tick_cv:
                     self._tick_cv.notify_all()
                 return _PendingStep(
@@ -877,6 +898,7 @@ class ServeEngine:
                     result={"finished": [], "generated": 0, "active": 0})
             self._m_active.set(self.active_count)
 
+            ph.mark("plan")
             # 2) size each slot's chunk under the global token budget:
             #    every active slot gets 1 guaranteed token; prefilling slots
             #    grow toward the prefill chunk (never past the prompt end),
@@ -915,6 +937,7 @@ class ServeEngine:
                             leftover -= int(d.size)
                 nvalid[s] = n
 
+            ph.mark("build")
             # 3) build this tick's ragged inputs: [B, C] token block per
             #    slot, per-slot start position + valid length
             token = np.zeros((self.slots, C), np.int32)
@@ -975,17 +998,14 @@ class ServeEngine:
             for s in range(self.slots):
                 if self.active[s] is not None:
                     self._m_chunk.observe(int(nvalid[s]))
-            self._profile_tick_start()
-            if tracing:
-                self.trace.begin(0, "device_step",
-                                 args={"tokens_fed": fed,
-                                       "active": self.active_count})
+            ph.mark("dispatch")
             outs = self._step(*args)     # async dispatch: the device is
             #                              now computing; nothing below in
             #                              step_end blocks until np.asarray
         p = _PendingStep(outs=outs, nvalid=nvalid, ndraft=ndraft,
                          t0=t0, fed=fed, tracing=tracing)
         self._pending = p
+        ph.mark(None)
         return p
 
     def step_end(self, pending: Optional[_PendingStep] = None) -> Dict[str, object]:
@@ -1002,14 +1022,11 @@ class ServeEngine:
         t0, tracing, fed = p.t0, p.tracing, p.fed
         nvalid, ndraft, outs = p.nvalid, p.ndraft, p.outs
         paged = self.cache_cfg.paged
+        ph = self.phases
+        ph.mark("wait")
         with jax.set_mesh(self.mesh):
-            if tracing:
-                # time the device work to completion — dispatch is
-                # serialized under tracing, so trace runs are for
-                # inspection, never benchmark rows
-                jax.block_until_ready(outs)
-                self.trace.end(0, "device_step")
-            self._profile_tick_end()
+            # the first np.asarray blocks until the step's outputs are on
+            # the host: the end of engine.wait
             if self.speculate_k:
                 out_tok, n_emit, acc, done, self.cache = outs
                 out_tok = np.asarray(out_tok)
@@ -1019,6 +1036,7 @@ class ServeEngine:
                 next_tok, done, self.cache = outs
                 next_tok = np.asarray(next_tok)
             done = np.asarray(done)
+            t_host = ph.mark("advance")
 
             # 5) advance slot state by consumed chunk lengths; collect
             #    sampled tokens; free finished
@@ -1088,6 +1106,7 @@ class ServeEngine:
                     self._m_emit.inc()
                     if was_first:
                         req.first_token_tick = self.tick
+                        req.first_token_ns = t_host
                         req.status = DECODE
                         if tracing:
                             self.trace.end(req.rid + 1, "prefill")
@@ -1095,6 +1114,7 @@ class ServeEngine:
                     if bool(done[s]):
                         # in-step termination: stop-token hit or length cap
                         req.finish_tick = self.tick
+                        req.finish_ns = t_host
                         req.status = FINISHED
                         req.finish_reason = (
                             "stop" if tok in req.sampling.stop_token_ids
@@ -1111,6 +1131,10 @@ class ServeEngine:
                         self._m_ttft.observe(req.ttft_ticks)
                         self._m_lat.observe(req.latency_ticks)
                         self._m_glen.observe(req.n_generated)
+                        self._m_ttft_s.observe(
+                            (req.first_token_ns - req.submit_ns) * 1e-9)
+                        self._m_wait_s.observe(
+                            (req.admit_ns - req.submit_ns) * 1e-9)
                         if tracing:
                             self.trace.end(req.rid + 1, "decode")
                             self.trace.instant(
@@ -1143,19 +1167,16 @@ class ServeEngine:
             # freed capacity becomes admission headroom the SAME tick: a
             # stop-token hit admits the queue head before the tick closes
             # (its first chunk runs next tick)
+            ph.mark("readmit")
             if finished:
-                if tracing:
-                    self.trace.begin(0, "admit")
                 self._admit()
-                if tracing:
-                    self.trace.end(0, "admit")
+        self._close_tick({"generated": generated})
         self.tick += 1
         self._m_tick_s.observe(time.perf_counter() - t0)
         self._m_tick_tok.observe(generated)
         if tracing:
             self.trace.counter("engine", {"active": self.active_count,
                                           "queue": self.sched.queue_depth})
-            self.trace.end(0, "tick", args={"generated": generated})
         with self._tick_cv:
             self._tick_cv.notify_all()
         return {"finished": finished, "generated": generated,
@@ -1199,22 +1220,11 @@ class ServeEngine:
         behind stats()['tokens_per_step'])."""
         return int(self._m_emit.value)
 
-    def _profile_tick_start(self) -> None:
-        """Start the optional jax.profiler capture on the first served
-        tick (`ObsConfig.jax_profile_ticks`). Profiling was asked for, so
-        a failure to start raises."""
-        if self._prof_ticks_left <= 0 or self._prof_active:
-            return
-        jax.profiler.start_trace(self.obs.jax_profile_dir)
-        self._prof_active = True
-
-    def _profile_tick_end(self) -> None:
-        if not self._prof_active:
-            return
-        self._prof_ticks_left -= 1
-        if self._prof_ticks_left <= 0:
-            jax.profiler.stop_trace()
-            self._prof_active = False
+    def _close_tick(self, args: Dict[str, object]) -> None:
+        """File this tick's phases (`TickPhases.close_tick`) and, when
+        tracing, export them nested in the tick's span."""
+        phases = self.phases.close_tick(self.tick)
+        self.trace.nested(0, "tick", phases, args={"tick": self.tick, **args})
 
     # ----------------------------------------------------------- accounting
     def kv_bytes_per_token(self) -> int:

@@ -96,6 +96,13 @@ class Request:
     first_step_tick: int = -1             # first tick whose step served us
     first_token_tick: int = -1            # tick that produced tokens[0]
     finish_tick: int = -1
+    # the same moments on the wall clock (`repro.obs.trace.now_ns`, the JAX
+    # profiler's host clock), engine-filled: submit, first admission, the
+    # host receiving tokens[0], finish; -1 until they happen
+    submit_ns: int = -1
+    admit_ns: int = -1
+    first_token_ns: int = -1
+    finish_ns: int = -1
     finish_reason: str = ""               # "stop" (EOS/stop id) | "length"
     slot: int = -1
     status: str = QUEUED                  # lifecycle (REQUEST_STATUSES)

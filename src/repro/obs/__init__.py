@@ -5,8 +5,9 @@ Three layers, wired through the serving stack (see docs/observability.md):
   * `obs.metrics`  — labelled counters/gauges/histograms with Prometheus
     text exposition and JSONL snapshots; `ServeEngine.stats()` is computed
     from this registry;
-  * `obs.trace`    — per-request lifecycle spans + per-tick device-step
-    spans as Chrome trace-event JSON (load in Perfetto);
+  * `obs.trace`    — engine tick phases on the JAX profiler's clock
+    (always recorded, bounded; also profiler annotations) and per-request
+    lifecycle spans as Chrome trace-event JSON (load in Perfetto);
   * `obs.cost`     — analytic HBM-byte / FLOP floors per engine-step
     signature, accumulated per tick and per request, plus the compiled
     step's parsed HLO cost as the achieved side.
@@ -34,12 +35,12 @@ from repro.obs.metrics import (
     parse_prom,
     ticker_line,
 )
-from repro.obs.trace import TraceRecorder, validate_events
+from repro.obs.trace import TickPhases, TraceRecorder, now_ns, validate_events
 
 __all__ = [
     "ObsConfig", "MetricsRegistry", "NULL_REGISTRY",
     "Counter", "Gauge", "Histogram", "parse_prom", "ticker_line",
-    "TraceRecorder", "validate_events",
+    "TickPhases", "TraceRecorder", "now_ns", "validate_events",
     "StepCostModel", "build_cost_model", "attribution", "hlo_step_cost",
     "kv_vector_bytes_floor", "kv_vector_bytes_ideal",
 ]

@@ -5,24 +5,26 @@ frozen-dataclass style as `cache.CacheConfig`:
 
   * ``enabled=True``  (default) — the engine creates a live
     `MetricsRegistry` and emits counters/gauges/histograms on every tick;
-    `ServeEngine.stats()` is computed from the registry. Recording is a
-    handful of float adds per tick on pre-resolved instruments, so the
-    measured system is not perturbed (asserted by the bench
+    `ServeEngine.stats()` is computed from the registry. It also records
+    every tick's phases (`obs.trace.TickPhases`: a bounded in-memory
+    record on the JAX profiler's clock, a counter per phase, and a
+    ``jax.profiler.TraceAnnotation`` per phase). Recording is a handful of
+    float adds and clock reads per tick, and nothing waits on the device,
+    so the measured system is not perturbed (asserted by the bench
     ``--obs-check`` run and tests/test_obs.py).
   * ``enabled=False`` — every instrument is the shared no-op
     `NULL_REGISTRY` child: call sites stay branch-free and accumulated
     telemetry reads as zero. Pure-state stats (kv bytes/token, queue
     depth) remain real.
-  * ``trace=True`` — additionally record per-request lifecycle spans and
-    per-tick device-step spans (`obs.trace.TraceRecorder`). Device-step
-    spans are timed via ``jax.block_until_ready``, which SERIALIZES
-    dispatch — tracing is for inspection runs, not benchmark rows.
+  * ``trace=True`` — additionally keep per-request lifecycle spans and the
+    tick phases as a Chrome trace (`obs.trace.TraceRecorder`), on the same
+    clock. It adds no wait on the device either.
   * ``cost=True`` (default) — attach the analytic roofline cost model
     (`obs.cost.StepCostModel`) and accumulate per-tick / per-request
     floor-vs-achieved HBM byte accounting.
-  * ``jax_profile_ticks=N`` — capture the first N served ticks with
-    ``jax.profiler`` into ``jax_profile_dir`` (XLA-level trace; loads in
-    TensorBoard/Perfetto). 0 disables.
+
+A device-level capture is the operator's own ``jax.profiler`` session: the
+phase annotations show up in whatever window it covers.
 """
 
 from __future__ import annotations
@@ -35,14 +37,8 @@ class ObsConfig:
     """How much telemetry the serving engine records."""
 
     enabled: bool = True        # master switch: False -> no-op instruments
-    trace: bool = False         # record lifecycle + device-step spans
+    trace: bool = False         # keep lifecycle + tick spans (Chrome)
     cost: bool = True           # roofline floor/achieved byte accounting
-    jax_profile_ticks: int = 0  # capture the first N served ticks
-    jax_profile_dir: str = "/tmp/repro_jax_trace"
-
-    def __post_init__(self):
-        if self.jax_profile_ticks < 0:
-            raise ValueError("jax_profile_ticks must be >= 0")
 
     @property
     def trace_on(self) -> bool:

@@ -38,6 +38,9 @@ from typing import Callable, Dict, Iterable, List, Optional, Tuple
 # bucket defaults: engine ticks are ~ms on CPU, ~100us on device
 TIME_BUCKETS = (1e-4, 5e-4, 1e-3, 5e-3, 1e-2, 5e-2, 0.1, 0.5, 1.0, 5.0)
 COUNT_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0)
+# request-level waits: milliseconds on a short queue, minutes under a backlog
+LATENCY_BUCKETS = (1e-2, 5e-2, 0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
+                   120.0, 300.0)
 
 
 class _NullInstrument:

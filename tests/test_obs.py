@@ -10,7 +10,9 @@ The load-bearing contracts:
       histograms keep insertion order, so percentiles can't drift);
   (c) span lifecycle invariants hold under real traffic — queueing,
       chunked prefill, same-tick re-admission, speculative rollback:
-      strict LIFO nesting per track, every span closed at drain;
+      strict LIFO nesting per track, every span closed at drain; every
+      tick's phases tile it in order, sit on the JAX profiler's clock, and
+      never wait on the device;
   (d) the analytic KV floors in `obs.cost` are derived INDEPENDENTLY of
       `repro.cache` and must agree with the pool layout exactly — the
       measured engine bytes/token sits within 10% of the floor (it is
@@ -18,7 +20,9 @@ The load-bearing contracts:
   (e) the Prometheus exposition round-trips through `parse_prom`.
 """
 
+import glob
 import json
+import os
 
 import jax
 import numpy as np
@@ -32,6 +36,7 @@ from repro.launch.sampling import SamplingParams
 from repro.obs import (
     MetricsRegistry,
     ObsConfig,
+    TickPhases,
     TraceRecorder,
     attribution,
     build_cost_model,
@@ -42,6 +47,7 @@ from repro.obs import (
     validate_events,
 )
 from repro.obs.metrics import NULL_REGISTRY
+from repro.obs.trace import BEGIN_PHASES, END_PHASES, PHASE_PREFIX
 
 ARCH = "qwen2-7b"
 SCHEME = "fp5.33-e2m3"
@@ -143,16 +149,22 @@ class TestTrace:
         rec.thread(0, "engine")
         rec.begin(0, "tick")
         t["now"] += 3000
-        rec.begin(0, "device_step")
+        rec.begin(0, "engine.wait")
         t["now"] += 2000
-        rec.end(0, "device_step")
+        rec.end(0, "engine.wait")
         rec.instant(0, "finished")
         rec.counter("engine", {"active": 2})
         rec.end(0, "tick", args={"generated": 1})
+        # stamps taken elsewhere, exported as one span with its children
+        rec.nested(1, "tick", [("engine.admit", 9_000_000, 9_004_000),
+                               ("engine.plan", 9_004_000, 9_006_000)])
         assert rec.open_spans() == {}
         spans = validate_events(rec.events())
         names = [(n, d) for n, _, _, d in spans[0]]
-        assert ("tick", 0) in names and ("device_step", 1) in names
+        assert ("tick", 0) in names and ("engine.wait", 1) in names
+        assert spans[1] == [("engine.admit", 9000.0, 9004.0, 1),
+                            ("engine.plan", 9004.0, 9006.0, 1),
+                            ("tick", 9000.0, 9006.0, 0)]
         p = tmp_path / "trace.json"
         rec.save(str(p))
         dumped = json.loads(p.read_text())
@@ -163,7 +175,7 @@ class TestTrace:
         rec, _ = self._rec()
         rec.begin(0, "tick")
         with pytest.raises(RuntimeError, match="nesting"):
-            rec.end(0, "device_step")
+            rec.end(0, "engine.wait")
         with pytest.raises(RuntimeError, match="nesting"):
             rec.end(1, "never_opened")
 
@@ -224,22 +236,28 @@ class TestKVFloors:
 
 
 # =========================================================== engine-coupled
-def schedule():
-    """Mixed traffic over a shared 16-token prefix: more requests than
-    slots (queueing), greedy + sampled + stop-token streams (variable
-    length, both finish reasons), arrivals timed so no tick is idle."""
+def prompts():
+    """Four prompts over a shared 16-token prefix."""
     rng = np.random.default_rng(5)
     sys_prompt = rng.integers(0, 512, PREFIX)
-    mk = lambda n: np.concatenate([sys_prompt, rng.integers(0, 512, n)])
+    return [np.concatenate([sys_prompt, rng.integers(0, 512, n)])
+            for n in (5, 3, 7, 2)]
+
+
+def schedule(stop_id):
+    """Mixed traffic over a shared 16-token prefix: more requests than
+    slots (queueing), greedy + sampled + stop-token streams (variable
+    length, both finish reasons), arrivals timed so no tick is idle.
+    ``stop_id`` is the 4th token of the last prompt's greedy stream (the
+    `stop_id` fixture): greedy streams are batch-invariant, so that
+    request stops by the 4th token with finish_reason "stop"."""
+    p = prompts()
     return [
-        (0, mk(5), SamplingParams(max_tokens=6)),
-        (0, mk(3), SamplingParams(max_tokens=8)),
-        (2, mk(7), SamplingParams(temperature=0.9, top_p=0.9, seed=11,
-                                  max_tokens=6)),
-        # stop id 56 is this stream's (deterministic, seeded) 4th draw —
-        # the request terminates mid-stream with finish_reason "stop"
-        (3, mk(2), SamplingParams(max_tokens=10, seed=3, temperature=0.8,
-                                  stop_token_ids=(56, 101, 202))),
+        (0, p[0], SamplingParams(max_tokens=6)),
+        (0, p[1], SamplingParams(max_tokens=8)),
+        (2, p[2], SamplingParams(temperature=0.9, top_p=0.9, seed=11,
+                                 max_tokens=6)),
+        (3, p[3], SamplingParams(max_tokens=10, stop_token_ids=(stop_id,))),
     ]
 
 
@@ -268,26 +286,36 @@ def make_engine(obs=None, speculate_k=0):
 
 
 @pytest.fixture(scope="module")
-def traced():
+def stop_id():
+    """The 4th token of the last prompt's greedy stream, from a first run
+    of it alone (a stop id that random weights are sure to draw)."""
+    eng = make_engine(obs=ObsConfig(enabled=False))
+    toks = eng.submit(prompts()[3], max_tokens=4).result()
+    assert len(toks) == 4
+    return toks[3]
+
+
+@pytest.fixture(scope="module")
+def traced(stop_id):
     eng = make_engine(obs=ObsConfig(trace=True))
-    reqs, n_steps = drive(eng, schedule())
+    reqs, n_steps = drive(eng, schedule(stop_id))
     return eng, reqs, n_steps
 
 
 @pytest.fixture(scope="module")
-def spec_traced():
+def spec_traced(stop_id):
     eng = make_engine(obs=ObsConfig(trace=True), speculate_k=3)
     work = [(t, p, SamplingParams(max_tokens=sp.max_tokens))  # all greedy
-            for t, p, sp in schedule()]
+            for t, p, sp in schedule(stop_id)]
     reqs, n_steps = drive(eng, work)
     return eng, reqs, n_steps
 
 
 class TestZeroPerturbation:
-    def test_streams_and_ticks_identical_with_obs_off(self, traced):
+    def test_streams_and_ticks_identical_with_obs_off(self, traced, stop_id):
         eng, reqs, _ = traced
         off = make_engine(obs=ObsConfig(enabled=False))
-        reqs_off, _ = drive(off, schedule())
+        reqs_off, _ = drive(off, schedule(stop_id))
         assert eng.tick == off.tick
         for a, b in zip(reqs, reqs_off):
             assert a.tokens == b.tokens
@@ -302,6 +330,7 @@ class TestZeroPerturbation:
         # pure-state values stay real even with telemetry off
         assert s["kv_bytes_per_token"] > 0
         assert off.metrics is NULL_REGISTRY
+        assert off.phases.ticks() == []
 
 
 class TestStatsBackwardCompat:
@@ -375,14 +404,87 @@ class TestSpans:
             assert by["decode"][1] <= by["request"][1]
 
     def test_engine_tick_spans(self, traced):
+        """Every tick's phases tile it in order with no overlap: contiguous
+        inside step_begin and inside step_end, the seam between the two
+        halves belonging to the caller; the export nests them in the
+        tick's span."""
         eng, _, n_steps = traced
+        rec = eng.phases.ticks()
+        assert [t for t, _ in rec] == list(range(n_steps))
+        names = [PHASE_PREFIX + p for p in BEGIN_PHASES + END_PHASES]
+        for _, phases in rec:
+            assert [n for n, _, _ in phases] == names
+            assert all(a <= b for _, a, b in phases)
+            for (_, _, b), (_, a, _) in zip(phases, phases[1:]):
+                assert b <= a                              # no overlap
+            half = len(BEGIN_PHASES)
+            for part in (phases[:half], phases[half:]):    # tiled halves
+                assert all(x[2] == y[1] for x, y in zip(part, part[1:]))
         spans = self._tracks(eng)
         ticks = [x for x in spans[0] if x[0] == "tick"]
-        steps = [x for x in spans[0] if x[0] == "device_step"]
-        # the warmup tick traces too; every tick nests >= 1 device step
-        assert len(ticks) >= n_steps and len(steps) >= n_steps
+        kids = [x for x in spans[0] if x[0].startswith(PHASE_PREFIX)]
+        assert len(ticks) == n_steps and len(kids) == len(names) * n_steps
         assert all(d == 0 for _, _, _, d in ticks)
-        assert all(d == 1 for _, _, _, d in steps)
+        assert all(d == 1 for _, _, _, d in kids)
+        for (_, a, b, _), (_, phases) in zip(ticks, rec):
+            assert (a, b) == (phases[0][1] / 1e3, phases[-1][2] / 1e3)
+
+    def test_phase_counters_match_record(self, traced):
+        """The registry counter and the record are fed by one stamp per
+        boundary: per phase, the counter is the record's summed time."""
+        eng, _, _ = traced
+        rec = eng.phases.ticks()
+        for p in BEGIN_PHASES + END_PHASES:
+            ns = sum(b - a for _, phases in rec for n, a, b in phases
+                     if n == PHASE_PREFIX + p)
+            got = eng.metrics.value("serve_tick_phase_seconds_total", phase=p)
+            assert got == pytest.approx(ns * 1e-9, rel=1e-9, abs=1e-12)
+
+    def test_phase_record_is_bounded(self, traced):
+        assert traced[0].phases.capacity == 4096
+        t = {"now": 0}
+
+        def clock():
+            t["now"] += 1000
+            return t["now"]
+
+        m = MetricsRegistry()
+        ph = TickPhases(capacity=8, metrics=m, clock=clock)
+        for tick in range(20):
+            ph.begin_tick()
+            for p in BEGIN_PHASES[1:] + END_PHASES:
+                ph.mark(p)
+            ph.close_tick(tick)
+        rec = ph.ticks()
+        assert [tick for tick, _ in rec] == list(range(12, 20))
+        assert all(len(phases) == 7 for _, phases in rec)
+        # the counters keep every tick, the record only the last eight
+        assert m.value("serve_tick_phase_seconds_total", phase="admit") == \
+            pytest.approx(20 * 1e-6)
+
+    def test_tracing_never_blocks_on_the_device(self, monkeypatch, stop_id):
+        """No span waits for the device: a traced engine serves with
+        jax.block_until_ready unusable."""
+        def refuse(*_a, **_k):
+            raise AssertionError("block_until_ready inside the tick")
+        eng = make_engine(obs=ObsConfig(trace=True))
+        monkeypatch.setattr(jax, "block_until_ready", refuse)
+        reqs, _ = drive(eng, schedule(stop_id)[:2])
+        assert all(r.done for r in reqs) and eng.phases.ticks()
+
+    def test_request_wall_clock_stamps(self, traced):
+        eng, reqs, _ = traced
+        for r in reqs:
+            assert 0 < r.submit_ns <= r.admit_ns <= r.first_token_ns \
+                <= r.finish_ns
+        fin = eng.finished
+        parsed = parse_prom(eng.metrics.exposition())
+        assert parsed[("serve_request_ttft_seconds_count", ())] == len(fin)
+        assert parsed[("serve_queue_wait_seconds_count", ())] == len(fin)
+        assert parsed[("serve_request_ttft_seconds_sum", ())] == \
+            pytest.approx(sum(r.first_token_ns - r.submit_ns
+                              for r in fin) * 1e-9)
+
 
     def test_spans_survive_speculative_rollback(self, spec_traced):
         """Speculative traffic (drafts scored + rolled back in-step,
@@ -398,14 +500,67 @@ class TestSpans:
             names = [n for n, _, _, _ in spans[r.rid + 1]]
             assert names == ["queued", "prefill", "decode", "request"]
 
-    def test_spec_streams_unchanged_by_telemetry(self, spec_traced):
+    def test_spec_streams_unchanged_by_telemetry(self, spec_traced, stop_id):
         eng, reqs, _ = spec_traced
         off = make_engine(obs=ObsConfig(enabled=False), speculate_k=3)
         work = [(t, p, SamplingParams(max_tokens=sp.max_tokens))
-                for t, p, sp in schedule()]
+                for t, p, sp in schedule(stop_id)]
         reqs_off, _ = drive(off, work)
         for a, b in zip(reqs, reqs_off):
             assert a.tokens == b.tokens
+
+
+BENCHMARK_SPANS = {"submit", "step_begin", "step_end", "collect"}
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory, stop_id):
+    """One engine run inside a JAX profiler session: the capture's host
+    events and the engine's own phase record."""
+    from jax.profiler import ProfileData
+    d = str(tmp_path_factory.mktemp("prof"))
+    eng = make_engine()
+    drive(eng, schedule(stop_id)[:1])          # compile outside the capture
+    n0 = len(eng.phases.ticks())
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(d, profiler_options=opts)
+    try:
+        drive(eng, schedule(stop_id)[:2])
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)
+    pd = ProfileData.from_file(path)
+    start = dict(pd.find_plane_with_name("Task Environment").stats)[
+        "profile_start_time"]
+    host = [(e.name, e.start_ns, e.end_ns)
+            for line in pd.find_plane_with_name("/host:CPU").lines
+            for e in line.events]
+    return eng.phases.ticks()[n0:], start, host
+
+
+class TestProfilerClock:
+    def test_phases_sit_on_the_profiler_clock(self, profiled):
+        """Each phase is a profiler annotation of its own name, and its
+        stamps minus the session's profile_start_time are the capture's
+        times for it: the record and the trace share one clock."""
+        rec, start, host = profiled
+        ann = sorted((e for e in host if e[0].startswith(PHASE_PREFIX)),
+                     key=lambda e: e[1])
+        flat = [p for _, phases in rec for p in phases]
+        assert [n for n, _, _ in ann] == [n for n, _, _ in flat]
+        for (n, a, b), (_, ta, tb) in zip(flat, ann):
+            assert abs((a - start) - ta) < 2e6        # ns: within 2 ms
+            assert abs((b - start) - tb) < 2e6
+
+    def test_no_program_span_takes_a_benchmark_name(self, profiled, traced):
+        _, _, host = profiled
+        eng, _, _ = traced
+        names = {n for n, _, _ in host}
+        names |= {n for _, phases in eng.phases.ticks() for n, _, _ in phases}
+        names |= {e["name"] for e in eng.trace.events()}
+        assert any(n.startswith(PHASE_PREFIX) for n in names)
+        assert not names & BENCHMARK_SPANS
 
 
 class TestRoofline:
@@ -453,26 +608,5 @@ class TestRoofline:
 
 class TestObsConfig:
     def test_validation(self):
-        with pytest.raises(ValueError):
-            ObsConfig(jax_profile_ticks=-1)
         c = ObsConfig(enabled=False, trace=True, cost=True)
         assert not c.trace_on and not c.cost_on   # master switch wins
-
-    def test_jax_profiler_capture_is_best_effort(self, tmp_path, monkeypatch):
-        """jax_profile_ticks=N wraps the first N device steps and writes a
-        trace; profiling was asked for, so a profiler that cannot start
-        raises instead of serving on untraced."""
-        eng = make_engine(obs=ObsConfig(jax_profile_ticks=1,
-                                        jax_profile_dir=str(tmp_path)))
-        reqs, _ = drive(eng, schedule()[:1])
-        assert reqs[0].done
-        assert eng._prof_ticks_left == 0 and not eng._prof_active
-        assert list(tmp_path.rglob("*.xplane.pb"))
-
-        def refuse(_):
-            raise RuntimeError("no profiler here")
-        monkeypatch.setattr(jax.profiler, "start_trace", refuse)
-        eng = make_engine(obs=ObsConfig(jax_profile_ticks=1,
-                                        jax_profile_dir=str(tmp_path)))
-        with pytest.raises(RuntimeError, match="no profiler"):
-            drive(eng, schedule()[:1])
