@@ -23,7 +23,8 @@ score-mod/online-rowscale design, SNIPPETS.md Snippet 2):
   (c) **ragged rows** — a [B, c] chunk folds its c queries into the row
       dimension of one grid cell; per-query lengths ride SCALAR PREFETCH
       (`pltpu.PrefetchScalarGridSpec`) next to the (paged-only) block
-      table, so BlockSpec index_maps see them before the body runs.
+      table and per-slot page count, so BlockSpec index_maps see them
+      before the body runs.
 
 Two lowering tiers share the math:
 
@@ -37,7 +38,9 @@ Two lowering tiers share the math:
     template (`impl="pallas"`/`"pallas_interpret"`), one grid
     (B, kv_blocks) with the KV dimension innermost ("arbitrary"); each
     cell holds every local kv head of its block and keeps one (m, l, acc)
-    per head in VMEM scratch across the KV dimension.
+    per head in VMEM scratch across the KV dimension. The paged walk stops
+    at each slot's length: past the slot's last valid page a step fetches
+    no page and attends nothing (the contiguous walk covers every block).
 
 `attend_contiguous` is the dispatch the models cores call: it routes to
 the fused template when the impl asks for it, and raises when the case is
@@ -250,24 +253,26 @@ def row_lengths(len_ref, b, c: int, g: int):
     return out
 
 
-def online_softmax_step(qf, k_blk, v_blk, length, i, nb, o_ref,
-                        acc_ref, m_ref, l_ref, *, pv_dtype=jnp.float32):
+def softmax_init(acc_ref, m_ref, l_ref):
+    """Reset one kv head's running (m, l, acc) before its first KV block."""
+    m_ref[...] = jnp.full_like(m_ref, NEG_CLAMP)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def online_softmax_step(qf, k_blk, v_blk, length, i, acc_ref, m_ref, l_ref,
+                        *, pv_dtype=jnp.float32):
     """One KV block of flash-decode accumulation for ONE kv head — THE loop
     body every fused layout shares. qf [rows, hd] f32 (pre-scaled; rows =
     chunk*group for ragged blocks), k_blk [block, hd] / v_blk [block, hd_v]
-    f32, ``length`` a scalar or per-row [rows, 1] valid-key count; the refs
-    are that head's views of the output block and the scratch.
-    ``pv_dtype`` mirrors flash_decode's ``p.astype(v.dtype)`` before the PV
-    product (bf16 caches cast, AMS lattice values stay f32) so the oracle
-    and the kernel round alike."""
+    f32, ``length`` a scalar or per-row [rows, 1] valid-key count, ``i``
+    the block's index along the KV walk; the refs are that head's views of
+    the scratch. A block whose keys are all masked leaves (m, l, acc)
+    exactly as they were (p == 0, corr == 1), which is what lets the paged
+    walk skip it. ``pv_dtype`` mirrors flash_decode's ``p.astype(v.dtype)``
+    before the PV product (bf16 caches cast, AMS lattice values stay f32)
+    so the oracle and the kernel round alike."""
     block = k_blk.shape[0]
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_CLAMP)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
     s = jax.lax.dot_general(qf, k_blk, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [rows, block]
     k_pos = i * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
@@ -286,11 +291,13 @@ def online_softmax_step(qf, k_blk, v_blk, length, i, nb, o_ref,
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(i == nb - 1)
-    def _done():
-        l = l_ref[:, :1]
-        out = acc_ref[...] / jnp.maximum(l, 1e-20)
-        o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
+
+def softmax_finish(o_ref, acc_ref, l_ref):
+    """Normalize one kv head's accumulator into its output block; a row
+    that attended no key (l == 0, acc == 0) writes exact zeros."""
+    l = l_ref[:, :1]
+    out = acc_ref[...] / jnp.maximum(l, 1e-20)
+    o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 # --- K/V load hooks -------------------------------------------------------
@@ -333,23 +340,45 @@ def _make_load_ams(fmt, k_share: int, hd: int, hd_v: Optional[int]):
 
 
 def _make_body(*, load_kv, nb: int, chunk: int, g: int, kv_n: int, pv_dtype,
-               num_scalars: int):
+               num_scalars: int, bounded: bool = False):
     """Assemble one kernel body from a load hook: one grid cell walks one
     KV block for every local kv head in turn. Ref order is fixed by the
     grid spec: [scalar prefetch...(lengths last), q, *kv operands, out,
-    acc, m, l]."""
+    acc, m, l]. With ``bounded`` the scalar stream before the lengths is
+    the per-slot block count [B]: a cell at or past it loads and attends
+    nothing (every key there is masked, so skipping it changes no bit),
+    while the init at the first cell and the normalize-and-store at the
+    last still run, so an idle slot (count 0) writes exact zeros."""
     def body(*refs):
         len_ref = refs[num_scalars - 1]
         q_ref = refs[num_scalars]
         kv_refs = refs[num_scalars + 1:-4]
         o_ref, acc_ref, m_ref, l_ref = refs[-4:]
         b, i = pl.program_id(0), pl.program_id(1)
-        lengths = row_lengths(len_ref, b, chunk, g)
-        for h in range(kv_n):
-            k_blk, v_blk = load_kv(kv_refs, h)
-            online_softmax_step(q_ref[0, h].astype(jnp.float32), k_blk, v_blk,
-                                lengths, i, nb, o_ref.at[0, h], acc_ref.at[h],
-                                m_ref.at[h], l_ref.at[h], pv_dtype=pv_dtype)
+
+        @pl.when(i == 0)
+        def _init():
+            for h in range(kv_n):
+                softmax_init(acc_ref.at[h], m_ref.at[h], l_ref.at[h])
+
+        def attend():
+            lengths = row_lengths(len_ref, b, chunk, g)
+            for h in range(kv_n):
+                k_blk, v_blk = load_kv(kv_refs, h)
+                online_softmax_step(q_ref[0, h].astype(jnp.float32), k_blk,
+                                    v_blk, lengths, i, acc_ref.at[h],
+                                    m_ref.at[h], l_ref.at[h],
+                                    pv_dtype=pv_dtype)
+
+        if bounded:                      # the page count precedes lengths
+            pl.when(i < refs[num_scalars - 2][b])(attend)
+        else:
+            attend()
+
+        @pl.when(i == nb - 1)
+        def _done():
+            for h in range(kv_n):
+                softmax_finish(o_ref.at[0, h], acc_ref.at[h], l_ref.at[h])
     return body
 
 
@@ -422,11 +451,19 @@ def fused_paged_attention(
     """Paged flash-decode through the template. Requires the group-major
     GQA head layout (the only layout the model zoo emits — see
     `kv_index_map`); returns q's shape in q.dtype. One grid step attends
-    one (slot, page) cell for every local kv head; the block table and the
-    flattened per-query lengths ride the same scalar-prefetch stream, so
-    each page's
-    BlockSpec index_map dereferences ``block_table[b, i]`` BEFORE the body
-    runs and the pipeline DMAs exactly the pages the slot owns."""
+    one (slot, page) cell for every local kv head; the block table, the
+    per-slot page count and the flattened per-query lengths ride the same
+    scalar-prefetch stream, so each page's BlockSpec index_map dereferences
+    ``block_table[b, i]`` BEFORE the body runs.
+
+    The walk stops at each slot's length: the page count is
+    ``ceil(max_j lengths[b, j] / page_size)`` (the longest query row, since
+    a ragged chunk's trailing rows are 0; an idle slot counts 0). Steps
+    past it keep the last valid page's block index, so the pipeline issues
+    no DMA for them whatever the row's tail holds, and skip the load and
+    the accumulate. Those pages' keys are all masked, so the output is the
+    same, bit for bit, as attending them; a slot at full length does the
+    whole row's work."""
     kv_n = jax.tree.leaves(pool["k"])[0].shape[2]
     qf, lens, chunked, dims = _fold_q(q, lengths, kv_n, scale)
     B, c, H, hd, g, rows = dims
@@ -434,15 +471,21 @@ def fused_paged_attention(
     page = page_size
     nb = block_table.shape[1]
     bt_flat = block_table.reshape(-1).astype(jnp.int32)
+    top = lens.reshape(B, c).max(axis=1)            # longest query row
+    npg = jnp.clip((top + page - 1) // page, 0, nb).astype(jnp.int32)
 
     # index maps: scalar-prefetch refs arrive after the grid indices
-    q_spec = pl.BlockSpec((1, kv_n, rows, hd), lambda b, i, bt, ln: (b, 0, 0, 0))
+    q_spec = pl.BlockSpec((1, kv_n, rows, hd),
+                          lambda b, i, bt, n, ln: (b, 0, 0, 0))
     out_spec = pl.BlockSpec((1, kv_n, rows, hd_v),
-                            lambda b, i, bt, ln: (b, 0, 0, 0))
+                            lambda b, i, bt, n, ln: (b, 0, 0, 0))
+
+    def page_index(b, i, bt, n, ln):
+        final = jnp.maximum(n[b] - 1, 0)
+        return (bt[b * nb + jnp.minimum(i, final)], 0, 0, 0)
 
     def page_spec(last):
-        return pl.BlockSpec((1, page, kv_n, last),
-                            lambda b, i, bt, ln: (bt[b * nb + i], 0, 0, 0))
+        return pl.BlockSpec((1, page, kv_n, last), page_index)
 
     if kv_scheme is not None:
         scheme = get_scheme(kv_scheme)
@@ -468,10 +511,10 @@ def fused_paged_attention(
         pv_dtype = jax.tree.leaves(pool["k"])[0].dtype
 
     body = _make_body(load_kv=load, nb=nb, chunk=c, g=g, kv_n=kv_n,
-                      pv_dtype=pv_dtype, num_scalars=2)
-    o = _launch(body, (B, nb), 2, in_specs, out_spec,
-                (bt_flat, lens), operands, kv_n=kv_n, rows=rows, hd_v=hd_v,
-                interpret=interpret)
+                      pv_dtype=pv_dtype, num_scalars=3, bounded=True)
+    o = _launch(body, (B, nb), 3, in_specs, out_spec,
+                (bt_flat, npg, lens), operands, kv_n=kv_n, rows=rows,
+                hd_v=hd_v, interpret=interpret)
     return _unfold_o(o, dims, hd_v, chunked, q.dtype)
 
 

@@ -488,6 +488,16 @@ class ServeEngine:
                                   "jitted engine-step invocations")
         self._m_fed = m.counter("serve_tokens_fed_total",
                                 "input positions fed through the step")
+        # the Pallas paged kernel walks (slot, page) cells of every layer's
+        # block table and stops at each slot's length: attended cells run
+        # the body, skipped ones fetch and attend nothing
+        pages = m.counter("serve_paged_attn_pages_total",
+                          "(slot, page, layer) cells of the paged attention "
+                          "grid, by kind", ("kind",))
+        self._m_pages_att = pages.labels(kind="attended")
+        self._m_pages_skip = pages.labels(kind="skipped")
+        self._paged_attn_layers = (
+            cfg.num_layers if ccfg.paged and ccfg.impl != "ref" else 0)
         self._m_chunk = m.histogram(
             "serve_chunk_tokens", "tokens fed per active slot per tick",
             buckets=COUNT_BUCKETS, keep_raw=False)
@@ -995,6 +1005,8 @@ class ServeEngine:
             fed = int(nvalid.sum())
             self._m_steps.inc()
             self._m_fed.inc(fed)
+            if self._paged_attn_layers:
+                self._count_paged_attn_pages(pos, nvalid)
             for s in range(self.slots):
                 if self.active[s] is not None:
                     self._m_chunk.observe(int(nvalid[s]))
@@ -1007,6 +1019,21 @@ class ServeEngine:
         self._pending = p
         ph.mark(None)
         return p
+
+    def _count_paged_attn_pages(self, pos: np.ndarray,
+                                nvalid: np.ndarray) -> None:
+        """Count this tick's paged-attention grid cells by the kernel's own
+        rule: a slot attends ceil(longest query length / page) pages of its
+        row (its last valid query sees pos + nvalid keys; an idle slot, pos
+        < 0, none) in every layer, and skips the rest of the row."""
+        page = self.cache_cfg.page_size
+        width = self.block_tables.shape[1]
+        top = np.where(pos >= 0, pos + nvalid, 0)
+        npg = np.minimum(-(-top // page), width)
+        attended = int(npg.sum()) * self._paged_attn_layers
+        self._m_pages_att.inc(attended)
+        self._m_pages_skip.inc(
+            self.slots * width * self._paged_attn_layers - attended)
 
     def step_end(self, pending: Optional[_PendingStep] = None) -> Dict[str, object]:
         """Second half of a tick: block on the in-flight device step,

@@ -12,9 +12,13 @@ is pinned to the same oracle family —
     oracle to f32-reduction tolerance across the full
     {gqa, mla} x {contiguous, paged_bf16, paged_ams} x chunk {1, 4} grid,
     idle slots / masked ragged rows flushing to EXACT zeros;
+  * the paged walk stops at each slot's length: pages past it are never
+    read (a NaN tail leaves the output finite) and skipping them changes
+    no bit against the contiguous template's walk over every block;
   * the whole engine decodes through the fused contiguous path.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -182,6 +186,118 @@ def test_fused_paged_matches_ref(kind, family, chunk):
         assert np.all(np.asarray(got)[1] == 0)       # idle slot: exact zeros
     else:
         assert np.all(np.asarray(got)[1, 1:] == 0)   # masked ragged rows
+
+
+# ----------------------------------- the paged walk stops at the slot length
+PAGE, WIDTH = 4, 4                    # 16 keys per slot, 4 pages a row
+TAIL_LENGTHS = {   # slot 0 long, slot 1 short (chunk 4: ragged rows), 2 idle
+    1: [13, 6, 0],
+    4: [[10, 11, 12, 13], [6, 0, 0, 0], [0, 0, 0, 0]],
+}
+
+
+def _owned_pool(kind, lengths, seed=0):
+    """(ccfg, pool, owned [S, WIDTH] page ids, npg [S]): a 3-slot pool whose
+    slots own distinct pages, written at every position of their OWNED
+    pages (the ones their longest query row reaches); no other page is
+    written."""
+    S = len(lengths)
+    ccfg = CacheConfig(kind=kind, page_size=PAGE, num_pages=4 * S * WIDTH
+                       ).sized(capacity=PAGE * WIDTH, slots=S)
+    rng = np.random.default_rng(seed)
+    top = np.asarray(lengths).reshape(S, -1).max(axis=1)
+    npg = -(-top // PAGE)
+    owned = rng.permutation(ccfg.num_pages)[: S * WIDTH].reshape(S, WIDTH)
+    pool = make_gqa_page_pool(ccfg, KV, HD)
+    bt = jnp.asarray(owned.astype(np.int32))
+    for t in range(PAGE * WIDTH):
+        k_new = jnp.asarray(rng.standard_normal((S, 1, KV, HD)), jnp.bfloat16)
+        v_new = jnp.asarray(rng.standard_normal((S, 1, KV, HD)), jnp.bfloat16)
+        pos = jnp.asarray(np.where(t < npg * PAGE, t, -1), jnp.int32)
+        pool = paged_insert(pool, k_new, v_new, pos, bt, ccfg)
+    return ccfg, pool, owned, npg
+
+
+def _query(lengths, seed=3):
+    rng = np.random.default_rng(seed)
+    S = len(lengths)
+    chunked = np.ndim(lengths) == 2
+    shape = (S, len(lengths[0]), H, HD) if chunked else (S, H, HD)
+    return (jnp.asarray(rng.standard_normal(shape), jnp.float32),
+            jnp.asarray(lengths, jnp.int32))
+
+
+def _fused(q, pool, lengths, bt, ccfg):
+    return np.asarray(fused_paged_attention(
+        q, pool, lengths, jnp.asarray(bt, jnp.int32), page_size=PAGE,
+        kv_scheme=ccfg.kv_scheme if ccfg.quantized else None,
+        interpret=True))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("chunk", [1, 4])
+@pytest.mark.parametrize("kind", ["paged_bf16", "paged_ams"])
+def test_fused_paged_never_reads_past_length(kind, chunk):
+    """Every page no slot owns is NaN and every row's tail points at those
+    pages, a different one per step: the kernel must never attend them,
+    so its output stays finite and matches the oracle on the clean pool;
+    the idle slot and the masked ragged rows stay exact zeros."""
+    lengths = TAIL_LENGTHS[chunk]
+    ccfg, pool, owned, npg = _owned_pool(kind, lengths)
+    S = len(lengths)
+    mine = np.zeros(ccfg.num_pages, bool)
+    for s in range(S):
+        mine[owned[s, :npg[s]]] = True
+    spare = iter(np.flatnonzero(~mine))
+    bt = owned.copy()
+    for s in range(S):
+        for i in range(npg[s], WIDTH):
+            bt[s, i] = next(spare)
+    dirty = jnp.asarray(~mine)[:, None, None, None]
+
+    def poison(a):
+        if jnp.issubdtype(a.dtype, jnp.floating):
+            return jnp.where(dirty, jnp.asarray(jnp.nan, a.dtype), a)
+        return jnp.where(dirty, jnp.asarray(-1, a.dtype), a)
+
+    nan_pool = jax.tree.map(poison, pool)
+    q, ln = _query(lengths)
+    got = _fused(q, nan_pool, ln, bt, ccfg)
+    assert np.all(np.isfinite(got))
+    dtype = jnp.float32 if ccfg.quantized else jnp.bfloat16
+    kd, vd = gather_kv(pool, jnp.asarray(bt), HD, ccfg, dtype=dtype)
+    kvm = np.arange(H) // (H // KV)
+    want = np.asarray(_oracle(q, kd, vd, ln, kvm, None))
+    atol, rtol = (2e-6, 1e-6) if ccfg.quantized else (2e-3, 2e-2)
+    np.testing.assert_allclose(got, want, atol=atol, rtol=rtol)
+    assert np.all(got[2] == 0)                       # idle slot
+    if chunk == 4:
+        assert np.all(got[1, 1:] == 0)               # masked ragged rows
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("full", [False, True], ids=["ragged", "full"])
+@pytest.mark.parametrize("kind", ["paged_bf16", "paged_ams"])
+def test_fused_paged_tail_is_bitwise_irrelevant(kind, full):
+    """The same pool and lengths through a 0-padded tail and through a tail
+    of other slots' real pages give the same bits, and both equal the
+    contiguous template's walk over EVERY block of the gathered rows (the
+    walk that attends the masked pages): stopping at the length is a pure
+    cut of work. At full length every slot attends its whole row."""
+    lengths = [16, 16, 16] if full else TAIL_LENGTHS[1]
+    ccfg, pool, owned, npg = _owned_pool(kind, lengths)
+    padded = np.where(np.arange(WIDTH)[None] < npg[:, None], owned, 0)
+    foreign = np.where(np.arange(WIDTH)[None] < npg[:, None], owned,
+                       np.roll(owned, 1, axis=0))
+    q, ln = _query(lengths)
+    got = _fused(q, pool, ln, padded, ccfg)
+    np.testing.assert_array_equal(got, _fused(q, pool, ln, foreign, ccfg))
+    dtype = jnp.float32 if ccfg.quantized else jnp.bfloat16
+    kd, vd = gather_kv(pool, jnp.asarray(padded), HD, ccfg, dtype=dtype)
+    whole = fused_contiguous_attention(q, kd, ln, v_cache=vd,
+                                       block_kv=PAGE, interpret=True)
+    np.testing.assert_array_equal(got, np.asarray(whole))
+    assert np.all(got[2] == 0) != full               # idle slot
 
 
 # ------------------------------------------------------- engine end-to-end

@@ -34,6 +34,7 @@ from repro.cache import (
     paged_truncate,
 )
 from repro.core.kv_quant import dequantize_kv, kv_bytes, quantize_kv
+from repro.launch.config import EngineConfig
 from repro.launch.engine import ServeEngine
 from repro.models.attention import kv_index_map
 
@@ -222,6 +223,37 @@ def test_paged_ams_engine_pallas_interpret_end_to_end():
                                                impl="pallas_interpret"))
     reqs = drive(eng, work)
     assert [len(r.tokens) for r in reqs] == [w[2] for w in work]
+
+
+@pytest.mark.slow
+def test_paged_attn_pages_counter_matches_hand_count():
+    """`serve_paged_attn_pages_total` counts the Pallas paged grid's cells
+    by the kernel's rule: each active slot attends ceil(longest query length
+    / page) pages of its row in every layer, the rest of the row and every
+    idle slot's row are skipped."""
+    eng = ServeEngine(EngineConfig(
+        arch=ARCH, scheme=SCHEME, slots=3, capacity=16, prefill_chunk=4,
+        cache=CacheConfig(kind="paged_ams", page_size=4,
+                          impl="pallas_interpret")))
+    eng.submit(np.arange(6), 8)
+    eng.submit(np.arange(3), 8)           # slot 2 stays idle
+    tops = []                             # each tick's longest query length
+    for _ in range(4):
+        eng.step()
+        tops.append(eng.fed.copy())
+    # slot 0 (prompt 6) reaches 4, 6, 7, 8 keys; slot 1 (prompt 3) 3, 4,
+    # 5, 6: pages 1+1, 2+1, 2+2, 2+2 of 4 a row
+    assert [list(t) for t in tops] == [[4, 3, 0], [6, 4, 0], [7, 5, 0],
+                                       [8, 6, 0]]
+    layers = eng.cfg.num_layers
+    width = eng.block_tables.shape[1]
+    assert width == 4
+    attended = (2 + 3 + 4 + 4) * layers
+    assert eng.metrics.value("serve_paged_attn_pages_total",
+                             kind="attended") == attended
+    skipped = eng.metrics.value("serve_paged_attn_pages_total",
+                                kind="skipped")
+    assert skipped == 3 * width * layers * 4 - attended
 
 
 def test_paged_ams_engine_matches_ref_oracle():
