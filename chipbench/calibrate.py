@@ -63,7 +63,8 @@ def main():
     cell = {c["name"]: c for c in spec["workloads"]}[args.workload]
     import traffic
     from model import load_model
-    m = load_model(cell["config"], rehearse=args.rehearse)
+    m = load_model(cell["config"], rehearse=args.rehearse,
+                   chips=cell["chips"])
     tr = traffic.load_traffic(cell["traffic"])
     if args.rehearse:
         tr = traffic.rehearsal(tr)

@@ -5,10 +5,12 @@
 
 For each cell: the engine step at the cell's slots, chunk, capacity and
 page pool; the weight maker's layer program; and the reference's layer
-program at the cell's padded length. Prints each program's
-``memory_analysis`` (argument, output and temporary bytes), so that a cell
-whose pool does not fit one chip is found here and not on the chip. A
-compile that passes is not a chip run.
+program at the cell's padded length. The step and the maker compile on a
+(1, chips) mesh of the cell's chips, placed as a run places them; the
+reference on one chip. Prints each program's ``memory_analysis``
+(argument, output and temporary bytes, on each chip), so that a cell whose
+pool does not fit a chip is found here and not on the chip. A compile that
+passes is not a chip run.
 """
 
 import json
@@ -56,11 +58,13 @@ def main(cells):
     from repro.launch.steps import build_engine_step
     for name in cells:
         cell = by[name]
-        m = load_model(cell["config"])
+        m = load_model(cell["config"], chips=cell["chips"])
         tr = traffic.load_traffic(cell["traffic"])
         cfg = system.program_config(m)
-        print(f"{name}:", flush=True)
-        mesh = Mesh(np.array([dev]).reshape(1, 1), ("data", "model"))
+        print(f"{name}: {m.chips} chip(s)", flush=True)
+        mesh = Mesh(np.array(topo.devices[:m.chips]).reshape(1, m.chips),
+                    ("data", "model"))
+        rep = NamedSharding(mesh, P())
         ccfg = CacheConfig(kind="paged_ams", page_size=m.page_size,
                            num_pages=tr.get("num_pages", 0), kv_scheme=m.kv,
                            kv_strategy=m.strategy, impl=m.cache_impl
@@ -68,25 +72,25 @@ def main(cells):
         rcfg = RunConfig(model=cfg, seq_len=tr["capacity"],
                          global_batch=tr["slots"], mode="decode",
                          quant=system._policy(m))
-        step, shapes, _ = build_engine_step(
+        step, shapes, placed = build_engine_step(
             mesh, cfg, rcfg, cache_cfg=ccfg, chunk=m.prefill_chunk,
             sampling=True)
         with jax.set_mesh(mesh):
-            args = jax.tree.map(
-                lambda s: jax.ShapeDtypeStruct(
-                    s.shape, s.dtype, sharding=NamedSharding(mesh, P())),
-                list(shapes.values()))
+            args = [jax.tree.map(
+                lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
+                tree, placed.get(n, jax.tree.map(lambda _: rep, tree)))
+                for n, tree in shapes.items()]
             a, o, t = describe(
                 f"engine step [{tr['slots']} x {m.prefill_chunk}], "
                 f"{ccfg.num_pages} pages x {ccfg.max_pages_per_seq} per slot",
                 step.lower(*args).compile())
         print(f"  engine step total {(a + t) / GiB:.3f} GiB of 16 GB "
-              f"({100 * (a + t) / 16e9:.1f} %)", flush=True)
+              f"({100 * (a + t) / 16e9:.1f} %) a chip", flush=True)
 
-        key = jax.ShapeDtypeStruct(seed_key(0).shape, seed_key(0).dtype,
-                                   sharding=one)
-
-        layers, outer = system.maker(m)
+        k0 = seed_key(0)
+        ref_key = jax.ShapeDtypeStruct(k0.shape, k0.dtype, sharding=one)
+        key = jax.ShapeDtypeStruct(k0.shape, k0.dtype, sharding=rep)
+        layers, outer = system.maker(m, mesh if m.chips > 1 else None)
         describe("weight maker, packed layers", layers.lower(key).compile())
         describe("weight maker, embedding and head",
                  outer.lower(key).compile())
@@ -94,8 +98,9 @@ def main(cells):
         x = jax.ShapeDtypeStruct((S, T, m.d_model), jnp.float32, sharding=one)
         layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
         describe(f"reference layer [{S} x {T}]",
-                 reference._layer.lower(key, layer, x, m=m, wfmt=m.weights,
-                                        kvfmt=m.kv, qblock=512).compile())
+                 reference._layer.lower(ref_key, layer, x, m=m,
+                                        wfmt=m.weights, kvfmt=m.kv,
+                                        qblock=512).compile())
 
 
 if __name__ == "__main__":

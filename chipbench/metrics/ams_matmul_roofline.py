@@ -1,7 +1,9 @@
-"""ams_matmul_roofline (%): least time of the traced ticks' packed linears
-(`work.tick_work`: max of FLOPs over peak FLOP/s and packed weight plus
-activation bytes over peak bandwidth, per linear) over the device time of
-the ``ams_matmul`` Pallas kernel. Moves tpot_ms.
+"""ams_matmul_roofline (%): one chip's share of the least time of the traced
+ticks' packed linears (`work.tick_work`: max of FLOPs over peak FLOP/s and
+packed weight plus activation bytes over peak bandwidth, per linear, the
+whole model's divided by the cell's chips) over one chip's device time of
+the ``ams_matmul`` Pallas kernel (`devtrace.kernel_seconds`: averaged over
+the chips traced). Moves tpot_ms.
 
 In a v5e trace the kernel runs as the ``tpu_custom_call`` instruction of
 the jitted ``ams_matmul_padded`` (read by hand from this benchmark's

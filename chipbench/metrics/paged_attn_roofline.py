@@ -1,7 +1,10 @@
-"""paged_attn_roofline (%): least time of the traced ticks' attention
-(`work.tick_work`: 4 x heads x head_dim FLOPs per query-key pair, and the
-packed K/V bytes of the keys each slot attends) over the device time of
-the paged attention template's Pallas kernel. Moves tpot_ms.
+"""paged_attn_roofline (%): one chip's share of the least time of the
+traced ticks' attention (`work.tick_work`: 4 x heads x head_dim FLOPs per
+query-key pair, and the packed K/V bytes of the keys each slot attends, the
+whole model's divided by the cell's chips, which hold its kv heads evenly)
+over one chip's device time of the paged attention template's Pallas
+kernel (`devtrace.kernel_seconds`: averaged over the chips traced). Moves
+tpot_ms.
 
 Its ``pallas_call`` carries no name: in a v5e trace it runs as a
 ``closed_call`` instruction that is a ``tpu_custom_call``, once per layer

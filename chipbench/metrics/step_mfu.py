@@ -1,8 +1,10 @@
 """step_mfu (%): useful model FLOPs of the traced ticks (linears over the
 tokens fed, attention over the keys each fed query attends, the LM head
 over active slots; `work.tick_work`) over the traced window times the
-chip's peak bf16 FLOP/s. Moves tpot_ms. It bounds what the kernels'
-roofline shares can claim end to end."""
+cell's chips times one chip's peak bf16 FLOP/s: `tick_work` gives one
+chip's share of the FLOPs, so it is divided by one chip's peak. Moves
+tpot_ms. It bounds what the kernels' roofline shares can claim end to
+end."""
 
 import devtrace
 

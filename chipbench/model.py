@@ -12,11 +12,18 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
+CONFIGS = os.path.join(BENCH, "configs")
+
+# what the weight maker and the reference build besides the sizes: a file
+# that states otherwise is refused by `load_model`, naming the key
+BUILT = {"tie_word_embeddings": False, "use_sliding_window": False,
+         "hidden_act": "silu"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +41,8 @@ class Model:
     vocab: int
     norm_eps: float
     rope_theta: float
+    attention_bias: bool    # q/k/v projections carry biases
+    rope_scaling: Optional[Tuple[str, float]]   # ("linear", factor) or None
     weights: str            # weight scheme, e.g. fp5.33-e2m3
     kv: str                 # paged KV scheme, e.g. fp4.25-e2m2
     strategy: str           # mantissa-sharing strategy
@@ -44,11 +53,31 @@ class Model:
     page_size: int
     prefill_chunk: int
     reduced: bool = False   # rehearsal size (CPU, interpret mode)
+    chips: int = 1          # the cell's chips: the tensor-parallel degree
 
 
-def load_model(name: str, rehearse: bool = False) -> Model:
-    with open(os.path.join(BENCH, "configs", f"{name}.json")) as f:
+def rope_scaling(value) -> Optional[Tuple[str, float]]:
+    """A ``rope_scaling`` as a file or a registry entry states it (absent,
+    ``None``, ``{"type": "linear", "factor": f}`` or ``("linear", f)``),
+    as ``("linear", f)`` or None; any other type is refused."""
+    if value is None:
+        return None
+    if isinstance(value, dict):
+        value = (value.get("type"), value.get("factor"))
+    kind, factor = value
+    if kind != "linear":
+        raise SystemExit(f"rope_scaling of type {kind!r}: the benchmark "
+                         f"builds only linear position scaling")
+    return ("linear", float(factor))
+
+
+def load_model(name: str, rehearse: bool = False, chips: int = 1) -> Model:
+    with open(os.path.join(CONFIGS, f"{name}.json")) as f:
         c = json.load(f)
+    for k, v in BUILT.items():
+        if c.get(k, v) != v:
+            raise SystemExit(f"{name}: {k} = {c[k]!r}; the benchmark builds "
+                             f"only {k} = {v!r}")
     sizes, lowering = dict(c), c["lowering"]
     if rehearse:
         sizes.update(c["rehearsal"])
@@ -62,12 +91,15 @@ def load_model(name: str, rehearse: bool = False) -> Model:
         n_kv=sizes["num_key_value_heads"], head_dim=sizes["head_dim"],
         d_ff=sizes["intermediate_size"], vocab=sizes["vocab_size"],
         norm_eps=sizes["rms_norm_eps"], rope_theta=sizes["rope_theta"],
+        attention_bias=c["attention_bias"],
+        rope_scaling=rope_scaling(c.get("rope_scaling")),
         weights=q["weights"], kv=q["kv_cache"], strategy=q["strategy"],
         control_weights=q["control"]["weights"],
         control_kv=q["control"]["kv_cache"],
         impl=lowering["impl"], cache_impl=lowering["cache_impl"],
         page_size=lowering["page_size"],
-        prefill_chunk=lowering["prefill_chunk"], reduced=rehearse)
+        prefill_chunk=lowering["prefill_chunk"], reduced=rehearse,
+        chips=chips)
 
 
 def seed_key(seed: int):
@@ -84,7 +116,8 @@ def _normal(key, shape, scale):
 def layer_floats(key, m: Model, layer):
     """One decoder layer's float32 weights, ``[in, out]`` for every matrix.
     Biases and norm gains are drawn away from 0 and 1 so that every add and
-    scale in the layer matters to the output."""
+    scale in the layer matters to the output. The draws keep one key each
+    whatever the configuration: a bias-free one leaves ``bq/bk/bv`` unused."""
     D, H, KV, hd, F = m.d_model, m.n_heads, m.n_kv, m.head_dim, m.d_ff
     ks = jax.random.split(jax.random.fold_in(key, layer), 12)
     return {

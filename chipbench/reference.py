@@ -1,9 +1,12 @@
 """Plain float32 reference of a served decoder, and the gap check.
 
-Written from the published description (Qwen2 / Qwen1.5: pre-norm RMSNorm,
-rotate-half RoPE, grouped-query attention with q/k/v biases, SwiGLU MLP,
-untied LM head) and from the AMS-Quant formats, with nothing imported from
-the program under test. It draws the same bf16 checkpoint as the weight
+Written from the published descriptions (Qwen2 / Qwen1.5 and the Llama
+block of DeepSeek-Coder: pre-norm RMSNorm, rotate-half RoPE, grouped-query
+attention, SwiGLU MLP, untied LM head; q/k/v biases where the configuration
+states ``attention_bias``, and RoPE positions divided by the factor of a
+linear ``rope_scaling``, as Hugging Face's linear-scaling rotary embedding
+does) and from the AMS-Quant formats, with nothing imported from the
+program under test. It draws the same bf16 checkpoint as the weight
 maker (`model.layer_floats`), quantizes it with its own quantizer, and runs
 every matrix product at ``highest`` precision, one layer at a time so that
 it fits beside nothing else on the chip.
@@ -106,10 +109,15 @@ def _rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
 
 
-def _rope(x, pos, theta):
+def _rope(x, pos, theta, scaling=None):
+    """Rotate-half RoPE at positions ``pos``; a linear ``scaling``
+    (``("linear", factor)``) divides the positions by its factor first."""
     hd = x.shape[-1]
     inv = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd))
-    ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv)
+    pos = pos.astype(jnp.float32)
+    if scaling is not None:
+        pos = pos / jnp.float32(scaling[1])
+    ang = pos[..., None] * jnp.asarray(inv)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
     x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
@@ -131,13 +139,18 @@ def _layer(key, layer, x, *, m: Model, wfmt: str, kvfmt: str, qblock: int):
     H, KV, hd = m.n_heads, m.n_kv, m.head_dim
     pos = jnp.arange(T)[None, :]
     h = _rms(x, f["ln1"], m.norm_eps)
-    q = (_mm(h, q_["wq"]) + f["bq"]).reshape(S, T, H, hd)
-    kk = (_mm(h, q_["wk"]) + f["bk"]).reshape(S, T, KV, hd)
-    v = (_mm(h, q_["wv"]) + f["bv"]).reshape(S, T, KV, hd)
-    q = _rope(q, pos, m.rope_theta)
+
+    def proj(n, heads):
+        y = _mm(h, q_[f"w{n}"])
+        if m.attention_bias:
+            y = y + f[f"b{n}"]
+        return y.reshape(S, T, heads, hd)
+
+    q, kk, v = proj("q", H), proj("k", KV), proj("v", KV)
+    q = _rope(q, pos, m.rope_theta, m.rope_scaling)
     # the cache quantizes the bf16 activations the step hands it
     kk, v = (a.astype(jnp.bfloat16).astype(jnp.float32)
-             for a in (_rope(kk, pos, m.rope_theta), v))
+             for a in (_rope(kk, pos, m.rope_theta, m.rope_scaling), v))
     kk = fake_quant_vectors(kk, kvfmt)
     v = fake_quant_vectors(v, kvfmt)
     g = H // KV

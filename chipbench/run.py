@@ -7,7 +7,11 @@ from the root of a checkout. The cell (``BENCHMARK.json``) names a
 configuration (``chipbench/configs/<name>.json``) and a traffic mix
 (``chipbench/traffic/<name>.json``); per-layer metrics are read by
 ``chipbench/metrics/<name>.py``; the comparison limit of a cell is in
-``chipbench/cells/<cell>.json``. Adding any of them needs no edit here.
+``chipbench/cells/<cell>.json``. Adding any of them needs no edit here, and
+neither does a configuration of another block (q/k/v biases or none, RoPE
+with linear position scaling or without) or a cell on more chips: the
+configuration file states the block, and the cell's ``chips`` is the
+tensor-parallel degree the weights are made, placed and served at.
 
 Set-up (``setup_s``, from process start to the window) makes the weights on
 the chip from the seed, builds the serving engine, and runs the closed
@@ -25,7 +29,8 @@ mix's rehearsal sizes, with the Pallas kernels in interpret mode; it is for
 checking the flow here, and no measured run takes it.
 
 The run exits non-zero, printing no result, when JAX finds no TPU or fewer
-chips than the cell asks for, or when the program is not in ``src/``.
+chips than the cell asks for (at rehearsal size: fewer CPU devices), or
+when the program is not in ``src/``.
 """
 
 import time
@@ -127,6 +132,7 @@ class Run:
         return [t for t in self.record.ticks if t.end <= self.record.seconds]
 
     def traced_work(self):
+        """`work.tick_work` summed over the traced ticks: one chip's share."""
         import work
         ticks = [t for t in self.record.ticks if t.traced]
         return work.sum_work(self.model, ticks, self.peak) if ticks else {}
@@ -225,12 +231,11 @@ def main(argv=None):
     cell = cells[args.workload]
     jax = start_jax(args.rehearse)
     devs = jax.devices()
-    if not args.rehearse:
-        if devs[0].platform != "tpu":
-            fail(f"no TPU: JAX found {len(devs)} {devs[0].platform} device(s)")
-        if len(devs) < cell["chips"]:
-            fail(f"{args.workload} needs {cell['chips']} chips, JAX found "
-                 f"{len(devs)}")
+    if not args.rehearse and devs[0].platform != "tpu":
+        fail(f"no TPU: JAX found {len(devs)} {devs[0].platform} device(s)")
+    if len(devs) < cell["chips"]:
+        fail(f"{args.workload} needs {cell['chips']} chips, JAX found "
+             f"{len(devs)}")
     devs = devs[:cell["chips"]]
 
     import numpy as np
@@ -240,7 +245,8 @@ def main(argv=None):
     import work
     from model import load_model
 
-    m = load_model(cell["config"], rehearse=args.rehearse)
+    m = load_model(cell["config"], rehearse=args.rehearse,
+                   chips=cell["chips"])
     tr = traffic.load_traffic(cell["traffic"])
     if args.rehearse:
         tr = traffic.rehearsal(tr)

@@ -63,6 +63,34 @@ def test_tick_work_counts_only_fed_work():
     assert work.tick_work(m, fed, np.zeros(4, int), peak)["step_flops"] == 0
 
 
+# tick_work of qwen2-7b-ams5 on the feed of test_tick_work_counts_only_fed_work,
+# as it read before it gave one chip's share
+PINNED = {"tokens": 17.0, "matmul_flops": 221859807232.0,
+          "matmul_bytes": 4441541290.666666,
+          "matmul_least_s": 0.005423127339031339,
+          "attn_flops": 140091392.0, "attn_bytes": 8823808.0,
+          "attn_least_s": 1.0773880341880342e-05,
+          "step_flops": 224179888128.0}
+
+
+def test_tick_work_is_one_chips_share():
+    """One chip reads as before; on four, each chip's least times, FLOPs
+    and bytes are a quarter of the whole model's, and the tokens stay the
+    tick's."""
+    import dataclasses
+    import numpy as np
+    m = load_model("qwen2-7b-ams5")
+    assert m.chips == 1
+    peak = work.peaks("TPU v5 lite")
+    fed, nvalid = np.array([100, 0, 7, 0]), np.array([1, 0, 16, 0])
+    one = work.tick_work(m, fed, nvalid, peak)
+    assert one == PINNED
+    four = work.tick_work(dataclasses.replace(m, chips=4), fed, nvalid, peak)
+    assert four["tokens"] == one["tokens"]
+    for k in PINNED.keys() - {"tokens"}:
+        assert four[k] == one[k] / 4, k
+
+
 def test_unknown_device_is_an_error():
     with pytest.raises(SystemExit):
         work.peaks("TPU v9 imaginary")

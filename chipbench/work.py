@@ -74,8 +74,11 @@ def layer_weight_bytes(m: Model) -> float:
 
 def tick_work(m: Model, fed: np.ndarray, nvalid: np.ndarray,
               peak: Dict[str, float]) -> Dict[str, float]:
-    """FLOPs, bytes and least times of one tick, from each slot's start
-    position ``fed`` and tokens fed ``nvalid`` (0 = idle slot).
+    """One chip's share of the FLOPs, bytes and least times of one tick,
+    from each slot's start position ``fed`` and tokens fed ``nvalid`` (0 =
+    idle slot): the whole model's divided by ``m.chips``. The linears, the
+    attention and the LM head are split over the chips by heads and output
+    channels, so an even split is the share a roofline is held to.
 
     * linears (``ams_matmul``): 2 x tokens x K x N FLOPs; bytes are the
       packed weight plus the fed tokens' bf16 inputs and outputs;
@@ -85,7 +88,7 @@ def tick_work(m: Model, fed: np.ndarray, nvalid: np.ndarray,
       the queries' bf16 inputs and outputs;
     * the step adds the LM head, 2 x D x V per active slot.
     Least time is max(FLOPs / peak FLOP/s, bytes / peak bytes/s), per layer
-    and per linear, summed."""
+    and per linear, summed. ``tokens`` is the whole tick's."""
     P, BW = peak["bf16_flops_per_s"], peak["hbm_bytes_per_s"]
     fed = np.asarray(fed, np.int64)
     nvalid = np.asarray(nvalid, np.int64)
@@ -106,14 +109,15 @@ def tick_work(m: Model, fed: np.ndarray, nvalid: np.ndarray,
     att_f = 4.0 * Hhd * pairs
     att_b = keys * kv_bytes_per_token(m) + 4.0 * T * Hhd
     head_f = 2.0 * int(act.sum()) * m.d_model * m.vocab
-    return {
-        "tokens": float(T),
+    whole = {
         "matmul_flops": L * lin_f, "matmul_bytes": L * lin_b,
         "matmul_least_s": L * lin_t,
         "attn_flops": L * att_f, "attn_bytes": L * att_b,
         "attn_least_s": L * max(att_f / P, att_b / BW),
         "step_flops": L * (lin_f + att_f) + head_f,
     }
+    return {"tokens": float(T),
+            **{k: v / m.chips for k, v in whole.items()}}
 
 
 def sum_work(m: Model, ticks, peak) -> Dict[str, float]:
